@@ -54,8 +54,11 @@ def _paged_rows(quick: bool):
     vc = jax.random.normal(ks[2], (B, Lmax, KV, hd), jnp.float32)
     nv = jnp.full((B,), live, jnp.int32)
     null = jnp.zeros((1, P, KV, hd), jnp.float32)
-    kp = jnp.concatenate([null, kc[:, :live].reshape(-1, P, KV, hd)], 0)
-    vp = jnp.concatenate([null, vc[:, :live].reshape(-1, P, KV, hd)], 0)
+    # kv-head-major pools [KV, N, P, hd], page 0 the null page
+    kp = jnp.moveaxis(jnp.concatenate(
+        [null, kc[:, :live].reshape(-1, P, KV, hd)], 0), 2, 0)
+    vp = jnp.moveaxis(jnp.concatenate(
+        [null, vc[:, :live].reshape(-1, P, KV, hd)], 0), 2, 0)
     bt = jnp.arange(1, 1 + B * npg, dtype=jnp.int32).reshape(B, npg)
 
     # --- decode µs/token at equal live tokens (CPU: both XLA ref paths;
@@ -90,16 +93,17 @@ def _paged_rows(quick: bool):
         fdc(dense_pool, slot_kv).block_until_ready()
         _, tdc = timeit(
             lambda: fdc(dense_pool, slot_kv).block_until_ready(), repeat=5)
-        # paged: O(S) scatter into [slots*npages, P, KV, hd], donated
+        # paged: O(S) scatter into [KV, slots*npages, P, hd], donated
         npages = Lmax // P
-        fpc = jax.jit(lambda p, o, pg, of: p.at[pg, of].set(o),
+        fpc = jax.jit(lambda p, o, pg, of:
+                      p.at[:, pg, of].set(jnp.swapaxes(o, 0, 1)),
                       donate_argnums=0)
         pg = jnp.repeat(jnp.arange(1, 1 + S // P, dtype=jnp.int32), P)
         of = jnp.tile(jnp.arange(P, dtype=jnp.int32), S // P)
-        paged_pool = jnp.zeros((1 + slots * npages, P, KV, hd))
+        paged_pool = jnp.zeros((KV, 1 + slots * npages, P, hd))
         paged_pool = fpc(paged_pool, one, pg, of)       # warm (donates)
         def run():
-            pool = jnp.zeros((1 + slots * npages, P, KV, hd))
+            pool = jnp.zeros((KV, 1 + slots * npages, P, hd))
             pool.block_until_ready()
             _, t = timeit(
                 lambda: fpc(pool, one, pg, of).block_until_ready(),

@@ -17,6 +17,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from benchmarks import (bench_annealing_params, bench_fit,
                             bench_goodput, bench_kernels,
                             bench_latency_pred, bench_move_ablation,

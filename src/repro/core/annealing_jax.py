@@ -146,7 +146,7 @@ def _pack(arrays_np: dict, model, pad_to: int) -> jnp.ndarray:
     """Build the padded per-request coefficient matrix [pad_to, 11].
 
     Built in float64 and converted by ``jnp.asarray`` so the dtype follows
-    the x64 flag (f32 by default, f64 under ``jax.experimental.enable_x64``
+    the x64 flag (f32 by default, f64 under ``jax.enable_x64(True)``
     for oracle-parity tests).  Padding rows are all-zero with VALID = 0:
     zero exec/prefill coefficients keep them out of batch durations and
     the latency sum, and the mask keeps them out of the met count.

@@ -161,9 +161,9 @@ def cache_specs(cache_shapes, cfg: ModelConfig, mesh: Mesh,
     Handles both layouts:
       * dense per-slot ``[B, L, kv, hd]`` caches (``init_cache``);
       * block-paged pools (``init_paged_cache``) — detected by the presence
-        of ``block_tables`` in the shapes pytree.  Page arrays
-        ``[num_blocks, block_size, kv, hd]`` shard on the kv-head axis
-        (dim 2) when the head count divides the tp axis; ``pos`` and
+        of ``block_tables`` in the shapes pytree.  Kv-head-major page
+        arrays ``[kv, num_blocks, block_size, hd]`` shard on the kv-head
+        axis (dim 0) when the head count divides the tp axis; ``pos`` and
         ``block_tables`` stay replicated so the host-side BlockPool,
         prefix-reuse, and CoW logic never see a sharded array.
     """
@@ -202,8 +202,8 @@ def cache_specs(cache_shapes, cfg: ModelConfig, mesh: Mesh,
 def _paged_cache_specs(cache_shapes, mesh: Mesh, par: ParallelismConfig):
     """Specs for the block-paged pool layout (see cache_specs docstring).
 
-    GQA kv-head groups stay whole per shard: sharding dim 2 of
-    ``[num_blocks, block_size, kv, hd]`` by the tp axis puts kv/tp full
+    GQA kv-head groups stay whole per shard: sharding dim 0 of
+    ``[kv, num_blocks, block_size, hd]`` by the tp axis puts kv/tp full
     heads on each device, and the query heads of each group shard the
     same way through ``wq``'s column shard — no cross-device attention.
     Per-slot SSM state (``conv``/``ssm``, leading dim = max_slots) and all
@@ -214,12 +214,9 @@ def _paged_cache_specs(cache_shapes, mesh: Mesh, par: ParallelismConfig):
     def layer_spec(layer):
         out = {}
         for k, v in layer.items():
-            if k in ("k", "v"):            # [N, P, kv, hd]
-                hspec = tp if _div(v.shape[2], mesh, tp) else None
-                out[k] = P(None, None, hspec, None)
-            elif k in ("k_scale", "v_scale"):  # [N, P, kv, 1]
-                hspec = tp if _div(v.shape[2], mesh, tp) else None
-                out[k] = P(None, None, hspec, None)
+            if k in ("k", "v", "k_scale", "v_scale"):  # [kv, N, P, hd|1]
+                hspec = tp if _div(v.shape[0], mesh, tp) else None
+                out[k] = P(hspec, None, None, None)
             elif k in ("ckv", "kpe"):      # [N, P, rank] — latent, no heads
                 out[k] = P(None, None, None)
             elif k == "conv":              # [max_slots, K-1, C]

@@ -4,20 +4,21 @@ Static-shape design (TPU-friendly): a fixed pool of ``max_slots`` request
 slots; prefills are padded to power-of-two length buckets; the decode
 step always runs over the full slot pool with inactive slots masked.
 
-KV memory comes in two layouts.  The default is a **block-paged pool**
-(vLLM-style): one shared ``[num_blocks, block_size, ...]`` page array
-per layer plus per-slot block tables, sized in *tokens* rather than
-``max_slots × max_seq_len``.  Prefill K/V is written *in place* into the
-slot's pages (an O(prompt) scatter under jit buffer donation — no
-per-prefill full-length cache allocation and no O(pool) commit copy),
-blocks are allocated on admit and freed on finish/preempt, and decode
-attends through the block table (the Pallas paged flash-decode kernel on
-TPU).  Admission is memory-aware: a request is admitted only while free
-blocks cover its prompt + output budget, and the block-pool occupancy is
-exposed to policies through ``SchedulerView.free_blocks``.
-``paged=False`` restores the dense ``max_slots × max_seq_len`` layout
-(kept for comparison benchmarks); SSM-only archs always use it — their
-state is O(1) in sequence length, so there is nothing to page.
+KV memory comes in two layouts. The default is a **block-paged pool**
+(vLLM-style): one shared page array per layer (K/V kv-head-major,
+``[kv, num_blocks, block_size, hd]``) plus per-slot block tables, sized
+in *tokens* rather than ``max_slots × max_seq_len``. Prefill K/V is written
+*in place* into the slot's pages (an O(prompt) scatter under jit buffer
+donation — no per-prefill full-length cache allocation and no O(pool)
+commit copy), blocks are allocated on admit and freed on finish/preempt,
+and decode attends through the block table (the Pallas paged
+flash-decode kernel on TPU). Admission is memory-aware: a request is
+admitted only while free blocks cover its prompt + output budget, and
+the block-pool occupancy is exposed to policies through
+``SchedulerView.free_blocks``. ``paged=False`` restores the dense
+``max_slots × max_seq_len`` layout (kept for comparison benchmarks);
+SSM-only archs always use it — their state is O(1) in sequence length,
+so there is nothing to page.
 
 Scheduling is delegated to the v2 API (:mod:`repro.core.policies`):
 ``run_policy`` accepts any :class:`SchedulingPolicy` — the same objects
@@ -90,7 +91,8 @@ class Engine:
                  profiler: Optional[LatencyProfiler] = None,
                  chunked_prefill: int = 0, paged: Optional[bool] = None,
                  block_size: int = 16, num_blocks: Optional[int] = None,
-                 prefix_cache: bool = True, mesh=None, parallelism=None):
+                 prefix_cache: bool = True, mesh=None, parallelism=None,
+                 kernel_mode: Optional[str] = None):
         """chunked_prefill > 0: split prompts into chunks of that size and
         interleave each chunk with a decode round for the running slots
         (Sarathi-style — new prompts no longer stall running decodes for
@@ -126,7 +128,15 @@ class Engine:
         paged layout.  ``parallelism`` overrides the
         :class:`~repro.distributed.sharding.ParallelismConfig` (default:
         tp on the ``model`` axis, no FSDP — serving replicates what it
-        cannot head-shard)."""
+        cannot head-shard).  A one-device mesh places the engine on that
+        device (how a fleet puts one replica on each chip).
+
+        ``kernel_mode`` is this engine's paged-decode attention path
+        (``kernels.ops``: ``pallas`` / ``interpret`` / ``ref``), resolved
+        once by :func:`repro.kernels.ops.resolve_mode` — the compiled
+        kernel on a TPU backend by default, the jnp gather reference
+        elsewhere and at tp > 1 — and closed over by this engine's jits
+        only; read it back from ``engine.kernel_mode``."""
         self.cfg = cfg
         self.params = params
         self.max_slots = max_slots
@@ -153,7 +163,7 @@ class Engine:
                                                   range(max_slots)]
             self.cache = init_paged_cache(cfg, max_slots, max_seq_len,
                                           num_blocks, block_size)
-            jit_kw = self._init_mesh(mesh, parallelism)
+            jit_kw = self._init_mesh(mesh, parallelism, kernel_mode)
             # the paged step fns donate the cache: page writes are
             # in-place scatters, never O(pool) copies (out_shardings
             # matching the committed input keeps donation exact under
@@ -175,8 +185,7 @@ class Engine:
                 raise ValueError(
                     "mesh execution requires the paged KV layout "
                     "(paged=True with an attention arch)")
-            self.mesh = None
-            self._jit_kw = {}
+            self._init_mesh(None, None, kernel_mode)
             self.pool = None
             self.prefix = None
             # slot pool: one batched dense cache over all slots
@@ -201,26 +210,27 @@ class Engine:
             self._dispatch_fn = jax.jit(self._decode_dispatch_dense)
 
     # ------------------------------------------------------------- mesh
-    def _init_mesh(self, mesh, parallelism):
-        """Commit params and the paged cache to their NamedShardings and
-        build the ``out_shardings`` kwargs the step jits pin outputs
-        with (logits/sampled tokens replicated — sampling and the host
+    def _init_mesh(self, mesh, parallelism, kernel_mode):
+        """Resolve the engine's kernel mode; under a mesh, commit params
+        and the paged cache to their NamedShardings and build the
+        ``out_shardings`` kwargs the step jits pin outputs with
+        (logits/sampled tokens replicated — sampling and the host
         scheduling paths read them — cache on its head-sharded specs).
 
         The Pallas paged-decode kernel does not partition under GSPMD,
-        so ``ops.set_tp_shards`` reroutes paged attention to the
-        pure-jnp gather reference whenever tp > 1 — XLA shards that on
-        the kv-head axis automatically (a ``shard_map`` wrap of the
-        kernel is the real-TPU follow-up; see docs/sharding.md).
+        so a tp > 1 engine declares the pure-jnp gather reference — XLA
+        shards that on the kv-head axis automatically (a ``shard_map``
+        wrap of the kernel is the follow-up; see docs/sharding.md).
         """
+        from repro.kernels import ops
         self.mesh = mesh
         self._jit_kw = {}
         if mesh is None:
+            self.kernel_mode = ops.resolve_mode(kernel_mode)
             return self._jit_kw
         from repro.distributed.sharding import (ParallelismConfig,
                                                 cache_specs, named,
                                                 param_specs)
-        from repro.kernels import ops
         par = parallelism if parallelism is not None \
             else ParallelismConfig(fsdp=False)
         self.parallelism = par
@@ -233,10 +243,18 @@ class Engine:
             mesh, cache_specs(shapes, self.cfg, mesh, par, self.max_slots))
         self._repl = NamedSharding(mesh, P())
         self.cache = jax.device_put(self.cache, self._cache_shardings)
-        ops.set_tp_shards(mesh.shape[par.tp_axis])
+        self.kernel_mode = ops.resolve_mode(kernel_mode,
+                                            mesh.shape[par.tp_axis])
         self._jit_kw = {
             "out_shardings": (self._repl, self._cache_shardings)}
         return self._jit_kw
+
+    def replicated(self, x):
+        """``x`` committed to the replicated sharding the step jits give
+        their logits and sampled tokens (unchanged without a mesh)."""
+        if getattr(self, "mesh", None) is None:
+            return x
+        return jax.device_put(x, self._repl)
 
     def _commit(self, cache):
         """Re-commit a cache pytree to its shardings.  Host round-trips
@@ -279,7 +297,8 @@ class Engine:
         (masked) token writes never touch live pages.  Per-slot state
         (pos, SSM conv/ssm) is still frozen."""
         logits, new_cache = forward_decode_paged(
-            params, self.cfg, tokens=tokens, cache=new_cache_arg(cache))
+            params, self.cfg, tokens=tokens, cache=new_cache_arg(cache),
+            kernel_mode=self.kernel_mode)
 
         def keep(new, old):
             mask = active.reshape((-1,) + (1,) * (new.ndim - 1))

@@ -1,14 +1,18 @@
 """Pallas TPU kernels for the perf-critical compute layers.
 
-Each kernel: <name>.py (pl.pallas_call + explicit BlockSpec VMEM tiling),
-a pure-jnp oracle in ref.py, and a jit'd dispatching wrapper in ops.py.
-Validated in interpret=True mode on CPU (tests/test_kernels.py); compiled
-on TPU backends.
+Each kernel: <name>.py (pl.pallas_call + explicit BlockSpec VMEM tiling)
+and a pure-jnp oracle in ref.py; ops.py dispatches the ones on the
+model's path by an explicit mode (pallas | interpret | ref).  Validated
+in interpret=True mode on CPU (tests/test_kernels.py, tests/test_paged.py);
+the paged-decode kernels are compile-checked for v5e
+(tests/test_tpu_compile.py).
 
   flash_attention       prefill attention (causal, sliding-window, GQA)
   flash_attention_vjp   differentiable variant (custom_vjp Pallas backward)
   decode_attention      flash-decode: one token vs a long KV cache
   decode_attention_q8   flash-decode over an int8-quantized KV cache
+  decode_attention_paged(_q8)  flash-decode through a block table (the
+                        served decode path)
   ssd_scan              Mamba2 SSD chunk scan with VMEM state carry
 """
 from repro.kernels import ops, ref

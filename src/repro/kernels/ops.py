@@ -1,64 +1,54 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Dispatching wrappers for the Pallas kernels on the model's path.
 
-Dispatch policy: on TPU backends the Pallas kernels run compiled; on CPU
-(this container) they run in ``interpret=True`` mode or fall back to the
-pure-jnp reference — selectable via ``set_kernel_mode``.  The model code
-calls these wrappers so swapping implementations never touches call sites.
+Every wrapper takes its kernel ``mode`` explicitly; there is no
+process-wide switch.  An :class:`~repro.engine.engine.Engine` resolves
+its mode once (:func:`resolve_mode`) and closes over it in its own jits,
+so engines sharing a process never change each other's path.
+
+  ``pallas``     the compiled kernel (TPU backends)
+  ``interpret``  the same kernel under the Pallas interpreter (CPU tests)
+  ``ref``        the pure-jnp oracle in ``ref.py`` (gather reference)
 """
 from __future__ import annotations
 
-from typing import Literal
+from typing import Literal, Optional
 
 import jax
 
 from repro.kernels import ref as _ref
-from repro.kernels.decode_attention import decode_attention as _decode_pallas
 from repro.kernels.decode_attention_paged import (
     decode_attention_paged as _decode_paged_pallas,
     decode_attention_paged_q8 as _decode_paged_q8_pallas)
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
-from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
-Mode = Literal["auto", "pallas", "interpret", "ref"]
-_MODE: Mode = "auto"
-_TP_SHARDS: int = 1
+Mode = Literal["pallas", "interpret", "ref"]
+MODES = ("pallas", "interpret", "ref")
 
 
-def set_kernel_mode(mode: Mode):
-    global _MODE
-    _MODE = mode
+def resolve_mode(mode: Optional[str] = None, tp: int = 1) -> str:
+    """The paged-decode kernel mode an engine declares.
 
-
-def set_tp_shards(n: int):
-    """Declare the tensor-parallel shard count the cache pages live under.
-
-    ``pallas_call`` does not auto-partition under GSPMD — running the paged
-    Pallas kernel inside a tp>1 jit would force XLA to gather the full page
-    pool onto every device.  Until the kernel is wrapped in ``shard_map``
-    (real-TPU follow-up, see docs/sharding.md), the paged dispatchers route
-    to the pure-jnp gather reference, which the partitioner shards on the
-    head axis automatically.
+    ``None`` picks the compiled kernel on a TPU backend and the jnp
+    reference elsewhere.  Under tensor parallelism (``tp > 1``) the paged
+    kernel is not partitioned (``pallas_call`` does not auto-partition
+    under GSPMD; its ``shard_map`` wrap is still open), so such an engine
+    declares ``ref``; asking it for a kernel mode is an error.
     """
-    global _TP_SHARDS
-    _TP_SHARDS = max(1, int(n))
-
-
-def _resolved() -> str:
-    if _MODE != "auto":
-        return _MODE
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
-
-def _paged_resolved() -> str:
-    mode = _resolved()
-    if _TP_SHARDS > 1 and mode in ("pallas", "interpret"):
+    if mode is not None and mode not in MODES:
+        raise ValueError(f"kernel mode {mode!r} not in {MODES}")
+    if tp > 1:
+        if mode not in (None, "ref"):
+            raise ValueError(
+                f"kernel mode {mode!r} needs tp == 1; a tp={tp} engine "
+                "decodes through the jnp gather reference ('ref')")
         return "ref"
+    if mode is None:
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
     return mode
 
 
-def flash_attention(q, k, v, *, causal=True, window=0,
+def flash_attention(q, k, v, *, mode: Mode, causal=True, window=0,
                     block_q=128, block_k=128):
-    mode = _resolved()
     if mode == "ref":
         return _ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return _flash_pallas(q, k, v, causal=causal, window=window,
@@ -66,18 +56,9 @@ def flash_attention(q, k, v, *, causal=True, window=0,
                          interpret=(mode == "interpret"))
 
 
-def decode_attention(q, k_cache, v_cache, n_valid, *, block_k=256):
-    mode = _resolved()
-    if mode == "ref":
-        return _ref.decode_attention_ref(q, k_cache, v_cache, n_valid)
-    return _decode_pallas(q, k_cache, v_cache, n_valid, block_k=block_k,
-                          interpret=(mode == "interpret"))
-
-
 def decode_attention_paged(q, k_pages, v_pages, block_tables, lengths, *,
-                           window=0):
-    """Flash-decode through a block table (paged KV pool)."""
-    mode = _paged_resolved()
+                           mode: Mode, window=0):
+    """Flash-decode through a block table (kv-head-major paged pool)."""
     if mode == "ref":
         return _ref.decode_attention_paged_ref(q, k_pages, v_pages,
                                                block_tables, lengths,
@@ -88,9 +69,9 @@ def decode_attention_paged(q, k_pages, v_pages, block_tables, lengths, *,
 
 
 def decode_attention_paged_q8(q, k_pages, k_scale, v_pages, v_scale,
-                              block_tables, lengths, *, window=0):
+                              block_tables, lengths, *, mode: Mode,
+                              window=0):
     """int8-KV paged flash-decode (per-(token, head) bf16 scales)."""
-    mode = _paged_resolved()
     if mode == "ref":
         from repro.models.cache import dequantize_kv
         kf = dequantize_kv(k_pages, k_scale)
@@ -100,11 +81,3 @@ def decode_attention_paged_q8(q, k_pages, k_scale, v_pages, v_scale,
     return _decode_paged_q8_pallas(q, k_pages, k_scale, v_pages, v_scale,
                                    block_tables, lengths, window=window,
                                    interpret=(mode == "interpret"))
-
-
-def ssd_scan(x, dt, A, B, C, *, chunk=64):
-    mode = _resolved()
-    if mode == "ref":
-        return _ref.ssd_ref(x, dt, A, B, C, chunk=chunk)
-    return _ssd_pallas(x, dt, A, B, C, chunk=chunk,
-                       interpret=(mode == "interpret"))

@@ -58,16 +58,19 @@ def decode_attention_paged_ref(q, k_pages, v_pages, block_tables, lengths,
     """Paged flash-decode oracle: gather live pages into the logical
     [B, L, kv, hd] view, then token-id ring masking.
 
-    q: [B,H,hd]; pages: [N,P,KV,hd]; block_tables: [B,pages_per_seq];
+    q: [B,H,hd]; pages: [KV,N,P,hd]; block_tables: [B,pages_per_seq];
     lengths: [B] int32 (context length incl. current token). -> [B,H,hd].
     """
     b, h, hd = q.shape
-    P = k_pages.shape[1]
-    kv = k_pages.shape[2]
+    kv, _, P, _ = k_pages.shape
     num_pages = block_tables.shape[1]
     L = num_pages * P
-    k_cache = k_pages[block_tables].reshape(b, L, kv, hd)
-    v_cache = v_pages[block_tables].reshape(b, L, kv, hd)
+
+    def gather(pages):                      # -> [B, L, kv, hd]
+        return jnp.moveaxis(pages[:, block_tables], 0, 3).reshape(
+            b, L, kv, hd)
+    k_cache = gather(k_pages)
+    v_cache = gather(v_pages)
     rep = h // kv
     if rep > 1:
         k_cache = jnp.repeat(k_cache, rep, axis=2)

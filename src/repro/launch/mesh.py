@@ -15,15 +15,24 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the partitioner
+    propagates shardings from the in/out specs, as the model code is
+    written for (JAX 0.9 defaults to ``Explicit`` axes)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
-def make_host_mesh(tp: Optional[int] = None, data: int = 1):
+def make_host_mesh(tp: Optional[int] = None, data: int = 1, devices=None):
     """A ``(data, tp)`` mesh over the local devices (tests/examples).
 
     ``tp`` defaults to every local device not claimed by ``data`` —
@@ -31,8 +40,10 @@ def make_host_mesh(tp: Optional[int] = None, data: int = 1):
     this is a (1, 8) tensor-parallel mesh, and on an ordinary
     single-device host it degrades to the old (1, 1) mesh.  Raises if
     the host cannot cover ``data * tp`` devices (jax.make_mesh checks).
+    ``devices`` pins the mesh to those devices (``[jax.devices()[i]]``
+    with ``tp=1`` places one fleet replica on chip ``i``).
     """
-    n = jax.local_device_count()
+    n = len(devices) if devices is not None else jax.local_device_count()
     if tp is None:
         tp = max(1, n // max(data, 1))
-    return jax.make_mesh((data, tp), ("data", "model"))
+    return _auto_mesh((data, tp), ("data", "model"), devices)

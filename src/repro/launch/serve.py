@@ -19,7 +19,9 @@ serving ticks alongside running decode dispatches (chunk-as-tick).
 ``--instances N`` scales streaming mode data-parallel: an
 :class:`repro.serving.EngineFleet` of N engines routed by ``--mapper``
 (least-loaded default; ``annealed`` runs the paper's Algorithm 2 as the
-routing plan — see docs/sharding.md).
+routing plan — see docs/sharding.md); replica *i* runs on
+``jax.devices()[i]``, and when N exceeds the device count replicas share
+devices round-robin.
 """
 from __future__ import annotations
 
@@ -36,6 +38,8 @@ from repro.core.profiler import LatencyProfiler
 from repro.data.synthetic import sample_serve_workload
 from repro.engine.engine import Engine
 from repro.engine.request import RuntimeRequest
+from repro.launch.compile_cache import setup_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import init_params
 from repro.serving import EngineFleet, ServeLoop
 
@@ -46,14 +50,38 @@ def _to_rts(pairs):
             for r, p in pairs]
 
 
-def fit_latency_model(cfg, params, max_batch, rng, n_warm=6):
-    """Fit the linear latency model on a short profiled warmup run."""
+def fit_latency_model(cfg, params, max_batch, rng, n_warm=10):
+    """Fit the linear latency model on a short profiled warmup run on
+    this process's device.  Raises when the run gave the profiler too
+    few samples to fit (rather than serving on another device's
+    coefficients)."""
     prof = LatencyProfiler()
     warm = Engine(cfg, params, max_slots=max_batch, max_seq_len=256,
                   profiler=prof)
     warm.run_fcfs(_to_rts(sample_serve_workload(n_warm, cfg.vocab_size,
                                                 rng=rng)))
-    return prof.fit()
+    if not prof.ready:
+        raise RuntimeError(
+            f"latency fit needs more samples: {len(prof.prefill_samples)} "
+            f"prefill, {len(prof.decode_samples)} decode from {n_warm} "
+            "warm-up requests")
+    # non-negative: the policies price schedules with it, and an
+    # extrapolated negative step time would make slack grow with load
+    return prof.fit(nonneg=True)
+
+
+def make_fleet_engines(cfg, params, n: int, **engine_kw):
+    """``n`` engines, replica ``i`` committed to ``jax.devices()[i]`` by
+    a one-device mesh; past the device count, replicas share devices
+    round-robin (``i % count``) — said out loud, since shared devices
+    serialize."""
+    devs = jax.devices()
+    if n > len(devs):
+        print(f"{n} replicas share {len(devs)} device(s): replica i runs "
+              f"on device i % {len(devs)}")
+    return [Engine(cfg, params,
+                   mesh=make_host_mesh(tp=1, devices=[devs[i % len(devs)]]),
+                   **engine_kw) for i in range(n)]
 
 
 def run_planner(eng, rts, model, discipline, max_batch, respect):
@@ -103,6 +131,7 @@ def main():
                     help="fleet routing: round-robin | least-loaded | "
                          "slo-affinity | memory-greedy | annealed")
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.reduced:
@@ -117,9 +146,19 @@ def main():
     discipline = make_discipline(args.discipline)
     model = fit_latency_model(cfg, params, args.max_batch, rng)
 
-    eng = Engine(cfg, params, max_slots=args.max_batch, max_seq_len=256)
     planner = args.policy in ("slo", "planned")
     mode = args.mode
+    fleet = mode == "stream" and not planner and args.instances > 1
+    if fleet and not cfg.attn_layers:
+        raise SystemExit("--instances places replicas through the paged "
+                         "KV layout, which needs attention layers")
+    engines = make_fleet_engines(cfg, params, args.instances,
+                                 max_slots=args.max_batch, max_seq_len=256) \
+        if fleet else [Engine(cfg, params, max_slots=args.max_batch,
+                              max_seq_len=256)]
+    eng = engines[0]
+    print(f"device={jax.devices()[0].device_kind} "
+          f"kernel_mode={eng.kernel_mode}")
     if mode == "stream" and not planner:
         pol = make(args.policy, model=model, max_batch=args.max_batch)
         # a policy that carries its own discipline (dynamic-chunk) wins
@@ -128,11 +167,7 @@ def main():
         # MLA + chunked raises UnsupportedDisciplineError, which is a
         # real configuration error the user must fix.
         disc = getattr(pol, "discipline", None) or discipline
-        if args.instances > 1:
-            engines = [eng] + [Engine(cfg, params,
-                                      max_slots=args.max_batch,
-                                      max_seq_len=256)
-                               for _ in range(args.instances - 1)]
+        if fleet:
             loop = EngineFleet(engines, args.policy, mapper=args.mapper,
                                model=model, discipline=disc,
                                overlap=not args.no_overlap)

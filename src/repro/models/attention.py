@@ -31,18 +31,8 @@ def _constrain(x, ctx, *spec):
         x, NamedSharding(ctx.mesh, P(*spec)))
 
 
-_USE_KERNELS = False
-
-
-def set_attention_kernels(enabled: bool):
-    """Route full-sequence attention through the Pallas flash kernel
-    (compiled on TPU; interpret/ref on CPU via kernels.ops mode)."""
-    global _USE_KERNELS
-    _USE_KERNELS = enabled
-
-
-def _use_attn_kernel(cfg, s: int) -> bool:
-    if not _USE_KERNELS or cfg.attn_logit_softcap:
+def _use_attn_kernel(cfg, s: int, attn_kernel) -> bool:
+    if attn_kernel is None or cfg.attn_logit_softcap:
         return False
     return s >= 16 and s % 16 == 0
 
@@ -91,11 +81,14 @@ def _causal_mask(q_len: int, kv_len: int, q_offset, window: int) -> jax.Array:
     return m
 
 
-def gqa_full(params, cfg, x, positions, *, cache_len: int = 0, ctx=None):
+def gqa_full(params, cfg, x, positions, *, cache_len: int = 0, ctx=None,
+             attn_kernel=None):
     """Full-sequence attention.
 
     x: [B, S, D]; positions: [B, S] (or [B, S, 3] for M-RoPE).
     Returns (out [B,S,D], (k, v) [B,S,kv,hd] for cache writing).
+    ``attn_kernel`` (a ``kernels.ops`` mode, default off) routes the
+    attention through the flash-attention dispatcher.
 
     With ``ctx.attn_sharding == "auto"``, sequence-parallel constraints are
     applied: q (and the scores' q dim) shard over the tp axis while k/v are
@@ -121,10 +114,9 @@ def gqa_full(params, cfg, x, positions, *, cache_len: int = 0, ctx=None):
         q = _constrain(q, ctx, dpb, tp, None, None)
         k = _constrain(k, ctx, dpb, None, None, None)
         v = _constrain(v, ctx, dpb, None, None, None)
-    if not seq_par and _use_attn_kernel(cfg, s):
-        # Pallas flash-attention path (TPU compiled / CPU interpret)
+    if not seq_par and _use_attn_kernel(cfg, s, attn_kernel):
         from repro.kernels import ops
-        out = ops.flash_attention(q, k, v, causal=True,
+        out = ops.flash_attention(q, k, v, mode=attn_kernel, causal=True,
                                   window=cfg.sliding_window)
         out = out.reshape(b, s, h * hd)
         return jnp.dot(out, params["wo"]), (k, v)
@@ -409,8 +401,8 @@ def _paged_write_token(layer_cache, k, v, block_tables, cache_pos,
     """Write one new token per sequence into its page (O(B) scatter).
 
     k/v: [B, 1, kv, hd]; returns the updated layer dict."""
-    from repro.models.cache import paged_token_write, quantize_kv
-    P = layer_cache["k"].shape[1]
+    from repro.models.cache import kv_token_write, quantize_kv
+    P = layer_cache["k"].shape[2]
     L = block_tables.shape[1] * P
     widx = jnp.mod(cache_pos, L)                              # [B]
     page_ids = jnp.take_along_axis(block_tables, (widx // P)[:, None],
@@ -419,34 +411,27 @@ def _paged_write_token(layer_cache, k, v, block_tables, cache_pos,
     if quantized:
         kq, ks = quantize_kv(k)
         vq, vs = quantize_kv(v)
-        return {
-            "k": paged_token_write(layer_cache["k"], kq[:, 0], page_ids, offs),
-            "v": paged_token_write(layer_cache["v"], vq[:, 0], page_ids, offs),
-            "k_scale": paged_token_write(layer_cache["k_scale"], ks[:, 0],
-                                         page_ids, offs),
-            "v_scale": paged_token_write(layer_cache["v_scale"], vs[:, 0],
-                                         page_ids, offs),
-        }
-    return {
-        "k": paged_token_write(layer_cache["k"], k[:, 0], page_ids, offs),
-        "v": paged_token_write(layer_cache["v"], v[:, 0], page_ids, offs),
-    }
+        vals = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    else:
+        vals = {"k": k, "v": v}
+    return {name: kv_token_write(layer_cache[name], x[:, 0], page_ids, offs)
+            for name, x in vals.items()}
 
 
 def gqa_decode_paged(params, cfg, x, positions, layer_cache, block_tables,
-                     cache_pos):
+                     cache_pos, kernel_mode: str = "ref"):
     """One-token decode against the paged KV pool.
 
     x: [B, 1, D]; layer_cache: {"k","v"[,"k_scale","v_scale"]} page
-    arrays [N, P, kv, hd]; block_tables: [B, pages_per_slot] int32;
+    arrays [kv, N, P, hd]; block_tables: [B, pages_per_slot] int32;
     cache_pos: [B] int32.  The new token is written in place into its
     page (O(B), not O(pool)), then attention runs through the block
-    table — the Pallas paged flash-decode kernel on TPU, the gather
-    reference on CPU (``kernels.ops`` dispatch).
+    table in ``kernel_mode`` (``kernels.ops``: the compiled Pallas paged
+    flash-decode kernel, its interpreter, or the jnp gather reference).
     Returns (out [B,1,D], new layer dict).
     """
     from repro.kernels import ops
-    from repro.models.cache import dequantize_kv, gather_pages
+    from repro.models.cache import dequantize_kv, gather_kv_pages
     b, s, d = x.shape
     assert s == 1
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -466,15 +451,15 @@ def gqa_decode_paged(params, cfg, x, positions, layer_cache, block_tables,
     if cfg.attn_logit_softcap:
         # the paged kernel (like the dense one) has no logit softcap —
         # gather the live pages and run the einsum path
-        P = new_cache["k"].shape[1]
+        P = new_cache["k"].shape[2]
         L = block_tables.shape[1] * P
-        k_eff = gather_pages(new_cache["k"], block_tables)
-        v_eff = gather_pages(new_cache["v"], block_tables)
+        k_eff = gather_kv_pages(new_cache["k"], block_tables)
+        v_eff = gather_kv_pages(new_cache["v"], block_tables)
         if quant:
-            k_eff = dequantize_kv(k_eff, gather_pages(new_cache["k_scale"],
-                                                      block_tables))
-            v_eff = dequantize_kv(v_eff, gather_pages(new_cache["v_scale"],
-                                                      block_tables))
+            k_eff = dequantize_kv(k_eff, gather_kv_pages(
+                new_cache["k_scale"], block_tables))
+            v_eff = dequantize_kv(v_eff, gather_kv_pages(
+                new_cache["v_scale"], block_tables))
         rep = h // kv
         qg = q.reshape(b, kv, rep, hd)
         scores = jnp.einsum("bgrd,blgd->bgrl", qg,
@@ -496,12 +481,12 @@ def gqa_decode_paged(params, cfg, x, positions, layer_cache, block_tables,
     elif quant:
         o = ops.decode_attention_paged_q8(
             q[:, 0], new_cache["k"], new_cache["k_scale"], new_cache["v"],
-            new_cache["v_scale"], block_tables, lengths,
+            new_cache["v_scale"], block_tables, lengths, mode=kernel_mode,
             window=cfg.sliding_window)
     else:
         o = ops.decode_attention_paged(
             q[:, 0], new_cache["k"], new_cache["v"], block_tables, lengths,
-            window=cfg.sliding_window)
+            mode=kernel_mode, window=cfg.sliding_window)
     out = o.astype(x.dtype).reshape(b, 1, h * hd)
     return jnp.dot(out, params["wo"]), new_cache
 
@@ -518,27 +503,25 @@ def gqa_continue_paged(params, cfg, x, positions, layer_cache, block_tables,
     while padded keys beyond it stay causally masked out of every valid
     query anyway.  Returns (out [B,C,D], new layer dict).
     """
-    from repro.models.cache import (dequantize_kv, gather_pages,
-                                    paged_prefill_write, quantize_kv)
+    from repro.models.cache import (dequantize_kv, gather_kv_pages,
+                                    kv_prefill_write, quantize_kv)
     c = x.shape[1]
     if n is None:
         n = c
     quant = "k_scale" in layer_cache
-    k_prefix = gather_pages(layer_cache["k"], block_tables)
-    v_prefix = gather_pages(layer_cache["v"], block_tables)
+    k_prefix = gather_kv_pages(layer_cache["k"], block_tables)
+    v_prefix = gather_kv_pages(layer_cache["v"], block_tables)
     if quant:
-        k_prefix = dequantize_kv(k_prefix,
-                                 gather_pages(layer_cache["k_scale"],
-                                              block_tables))
-        v_prefix = dequantize_kv(v_prefix,
-                                 gather_pages(layer_cache["v_scale"],
-                                              block_tables))
+        k_prefix = dequantize_kv(k_prefix, gather_kv_pages(
+            layer_cache["k_scale"], block_tables))
+        v_prefix = dequantize_kv(v_prefix, gather_kv_pages(
+            layer_cache["v_scale"], block_tables))
     out, k, v = _chunk_attend(params, cfg, x, positions, k_prefix, v_prefix,
                               start_pos)
     bt = block_tables[0]
 
     def write(pages, vals):
-        return paged_prefill_write(pages, vals[0], bt, n, start=start_pos)
+        return kv_prefill_write(pages, vals[0], bt, n, start=start_pos)
     if quant:
         kq, ks = quantize_kv(k)
         vq, vs = quantize_kv(v)

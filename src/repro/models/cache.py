@@ -15,8 +15,9 @@ Two layouts share those kinds:
 
 * **dense** (``init_cache``): one contiguous ``[B, L, ...]`` buffer per
   layer — HBM is priced by worst-case length per slot.
-* **paged** (``init_paged_cache``): one shared block pool
-  ``[num_blocks, block_size, ...]`` per layer plus per-slot block tables
+* **paged** (``init_paged_cache``): one shared block pool per layer
+  (attention K/V kv-head-major ``[kv, num_blocks, block_size, hd]``,
+  MLA latents ``[num_blocks, block_size, ...]``) plus per-slot block tables
   ``[B, pages_per_slot]`` — HBM is priced by *live tokens* (vLLM-style
   PagedAttention adapted to the static-shape TPU engine).  Block 0 is a
   reserved **null page**: unallocated table entries point at it and
@@ -134,9 +135,10 @@ def init_paged_cache(cfg: ModelConfig, max_slots: int, max_len: int,
       "layers":       [per-layer dict]
     }
 
-    attn -> {"k": [num_blocks, block_size, kv, hd], "v": ...}
-            (+ "k_scale"/"v_scale" [num_blocks, block_size, kv, 1] when
-            ``quantized``)
+    attn -> {"k": [kv, num_blocks, block_size, hd], "v": ...}
+            (+ "k_scale"/"v_scale" [kv, num_blocks, block_size, 1] when
+            ``quantized``) — kv-head-major, so one page of one head is a
+            contiguous ``[block_size, hd]`` tile (the paged kernel's block)
     mla  -> {"ckv": [num_blocks, block_size, rank], "kpe": [..., rope_d]}
     ssm  -> per-slot, identical to the dense layout.
     """
@@ -166,17 +168,17 @@ def init_paged_cache(cfg: ModelConfig, max_slots: int, max_len: int,
             kv, hd = cfg.num_kv_heads, cfg.head_dim
             if quantized:
                 layers.append({
-                    "k": jnp.zeros((num_blocks, P, kv, hd), jnp.int8),
-                    "v": jnp.zeros((num_blocks, P, kv, hd), jnp.int8),
-                    "k_scale": jnp.zeros((num_blocks, P, kv, 1),
+                    "k": jnp.zeros((kv, num_blocks, P, hd), jnp.int8),
+                    "v": jnp.zeros((kv, num_blocks, P, hd), jnp.int8),
+                    "k_scale": jnp.zeros((kv, num_blocks, P, 1),
                                          jnp.bfloat16),
-                    "v_scale": jnp.zeros((num_blocks, P, kv, 1),
+                    "v_scale": jnp.zeros((kv, num_blocks, P, 1),
                                          jnp.bfloat16),
                 })
             else:
                 layers.append({
-                    "k": jnp.zeros((num_blocks, P, kv, hd), dtype),
-                    "v": jnp.zeros((num_blocks, P, kv, hd), dtype),
+                    "k": jnp.zeros((kv, num_blocks, P, hd), dtype),
+                    "v": jnp.zeros((kv, num_blocks, P, hd), dtype),
                 })
     pages_per_slot = paged_slot_len(cfg, max_len, P, window) // P
     return {"pos": jnp.zeros((max_slots,), jnp.int32),
@@ -186,31 +188,55 @@ def init_paged_cache(cfg: ModelConfig, max_slots: int, max_len: int,
 
 
 def paged_token_write(pages, vals, page_ids, offs):
-    """Scatter one token per sequence into the pool.
+    """Scatter one token per sequence into a latent pool.
 
     pages: [N, P, ...]; vals: [B, ...]; page_ids/offs: [B] int32.
     O(B) — independent of pool size (and in-place under jit donation)."""
     return pages.at[page_ids, offs].set(vals.astype(pages.dtype))
 
 
-def paged_prefill_write(pages, vals, block_table, n, start=0):
-    """Scatter a prefilled span of ONE slot into its pages.
+def kv_token_write(pages, vals, page_ids, offs):
+    """:func:`paged_token_write` for a kv-head-major K/V pool.
 
-    pages: [N, P, ...]; vals: [S, ...] (first ``n`` rows valid — the rest
-    are padding); block_table: [pages_per_slot] int32; positions are
-    ``start .. start+S-1`` on the slot's logical ring of length
-    ``pages_per_slot * P``.  Padding rows and ring-evicted rows (when the
-    span wraps) are routed to the null page, so duplicate in-bound
-    indices never race.  O(S) — no O(pool) commit copy."""
-    S = vals.shape[0]
-    P = pages.shape[1]
+    pages: [KV, N, P, d]; vals: [B, KV, d]; page_ids/offs: [B] int32."""
+    return pages.at[:, page_ids, offs].set(
+        jnp.swapaxes(vals, 0, 1).astype(pages.dtype))
+
+
+def _prefill_targets(block_table, S, P, n, start):
+    """(page ids, in-page offsets) of a span of ``S`` rows at positions
+    ``start .. start+S-1`` on a slot's logical ring of length
+    ``pages_per_slot * P``.  Padding rows (beyond the first ``n``) and
+    ring-evicted rows (when the span wraps) are routed to the null page,
+    so duplicate in-bound indices never race."""
     L = block_table.shape[0] * P
     p = start + jnp.arange(S, dtype=jnp.int32)
     end = start + n
     keep = (p < end) & (p >= end - L)
     widx = jnp.mod(p, L)
-    page_ids = jnp.where(keep, block_table[widx // P], NULL_PAGE)
-    return pages.at[page_ids, jnp.mod(widx, P)].set(vals.astype(pages.dtype))
+    return (jnp.where(keep, block_table[widx // P], NULL_PAGE),
+            jnp.mod(widx, P))
+
+
+def paged_prefill_write(pages, vals, block_table, n, start=0):
+    """Scatter a prefilled span of ONE slot into its latent pages.
+
+    pages: [N, P, ...]; vals: [S, ...] (first ``n`` rows valid — the rest
+    are padding); block_table: [pages_per_slot] int32.  O(S) — no
+    O(pool) commit copy."""
+    page_ids, offs = _prefill_targets(block_table, vals.shape[0],
+                                      pages.shape[1], n, start)
+    return pages.at[page_ids, offs].set(vals.astype(pages.dtype))
+
+
+def kv_prefill_write(pages, vals, block_table, n, start=0):
+    """:func:`paged_prefill_write` for a kv-head-major K/V pool.
+
+    pages: [KV, N, P, d]; vals: [S, KV, d]."""
+    page_ids, offs = _prefill_targets(block_table, vals.shape[0],
+                                      pages.shape[2], n, start)
+    return pages.at[:, page_ids, offs].set(
+        jnp.swapaxes(vals, 0, 1).astype(pages.dtype))
 
 
 def write_prefill_paged(cache, layer_idx: int, kv_tuple, cfg: ModelConfig,
@@ -238,17 +264,15 @@ def write_prefill_paged(cache, layer_idx: int, kv_tuple, cfg: ModelConfig,
             kq, ks = quantize_kv(k)
             vq, vs = quantize_kv(v)
             layer = {
-                "k": paged_prefill_write(layer["k"], kq[0], bt, n),
-                "v": paged_prefill_write(layer["v"], vq[0], bt, n),
-                "k_scale": paged_prefill_write(layer["k_scale"], ks[0],
-                                               bt, n),
-                "v_scale": paged_prefill_write(layer["v_scale"], vs[0],
-                                               bt, n),
+                "k": kv_prefill_write(layer["k"], kq[0], bt, n),
+                "v": kv_prefill_write(layer["v"], vq[0], bt, n),
+                "k_scale": kv_prefill_write(layer["k_scale"], ks[0], bt, n),
+                "v_scale": kv_prefill_write(layer["v_scale"], vs[0], bt, n),
             }
         else:
             layer = {
-                "k": paged_prefill_write(layer["k"], k[0], bt, n),
-                "v": paged_prefill_write(layer["v"], v[0], bt, n),
+                "k": kv_prefill_write(layer["k"], k[0], bt, n),
+                "v": kv_prefill_write(layer["v"], v[0], bt, n),
             }
     cache["layers"][layer_idx] = layer
     return cache
@@ -263,20 +287,33 @@ def copy_page(cache, src: int, dst: int):
     for i, layer in enumerate(cache["layers"]):
         if "conv" in layer:
             continue
-        cache["layers"][i] = {k: v.at[dst].set(v[src])
-                              for k, v in layer.items()}
+        if "ckv" in layer:
+            cache["layers"][i] = {k: v.at[dst].set(v[src])
+                                  for k, v in layer.items()}
+        else:                       # kv-head-major: block axis is 1
+            cache["layers"][i] = {k: v.at[:, dst].set(v[:, src])
+                                  for k, v in layer.items()}
     return cache
 
 
 def gather_pages(pages, block_tables):
-    """Materialize the logical [B, L, ...] view of a paged layer.
+    """Materialize the logical [B, L, ...] view of a latent paged layer.
 
     pages: [N, P, ...]; block_tables: [B, pages_per_slot].  Gathers live
-    pages only — the XLA fallback for the Pallas paged-decode kernel and
-    the chunked-prefill prefix read."""
+    pages only — the MLA decode read."""
     b, npg = block_tables.shape
     g = pages[block_tables]                     # [B, pages, P, ...]
     return g.reshape((b, npg * pages.shape[1]) + pages.shape[2:])
+
+
+def gather_kv_pages(pages, block_tables):
+    """Materialize the logical [B, L, KV, d] view of a kv-head-major K/V
+    pool [KV, N, P, d] — the jnp gather reference for the Pallas
+    paged-decode kernel and the chunked-prefill prefix read."""
+    kv, _, P, d = pages.shape
+    b, npg = block_tables.shape
+    g = jnp.moveaxis(pages[:, block_tables], 0, 3)   # [B, pages, P, KV, d]
+    return g.reshape(b, npg * P, kv, d)
 
 
 def kv_bytes_per_token(cfg: ModelConfig, dtype=None,

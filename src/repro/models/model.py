@@ -111,10 +111,10 @@ def lm_head(params, cfg, x):
     return jnp.dot(x, params["lm_head"])
 
 
-def _attn_block_full(block, cfg, x, positions, ctx):
+def _attn_block_full(block, cfg, x, positions, ctx, attn_kernel=None):
     h, kv_out = (attn.mla_full if cfg.mla is not None else attn.gqa_full)(
         block["attn"], cfg, apply_norm(x, block["attn_norm"], cfg), positions,
-        ctx=ctx)
+        ctx=ctx, attn_kernel=attn_kernel)
     x = x + h
     y = apply_norm(x, block["mlp_norm"], cfg)
     aux = jnp.float32(0.0)
@@ -158,11 +158,13 @@ def _default_positions(cfg, batch, seq, offset=0):
 def forward_full(params, cfg: ModelConfig, *, tokens=None, embeds=None,
                  positions=None, cache=None,
                  ctx: Optional[ShardingCtx] = None, remat: bool = False,
-                 last_only: bool = False):
+                 last_only: bool = False, attn_kernel: Optional[str] = None):
     """Train / prefill pass over a whole sequence.
 
     ``last_only`` applies the LM head to the final position only (prefill
-    path — avoids materializing [B, S, V] logits).
+    path — avoids materializing [B, S, V] logits).  ``attn_kernel`` (a
+    ``kernels.ops`` mode) routes GQA attention through the flash kernel
+    dispatcher; None keeps the einsum path.
     Returns (logits, cache_or_None, aux_loss).
     """
     x = embeds if embeds is not None else embed_tokens(params, cfg, tokens)
@@ -195,7 +197,8 @@ def forward_full(params, cfg: ModelConfig, *, tokens=None, embeds=None,
                 return xx + h, state, jnp.float32(0.0)
         else:
             def layer_fn(xx, blk):
-                return _attn_block_full(blk, cfg, xx, positions, ctx)
+                return _attn_block_full(blk, cfg, xx, positions, ctx,
+                                        attn_kernel)
 
         if remat:
             layer_fn = jax.checkpoint(layer_fn)
@@ -252,14 +255,16 @@ def forward_decode(params, cfg: ModelConfig, *, tokens=None, embeds=None,
 
 def forward_decode_paged(params, cfg: ModelConfig, *, tokens=None,
                          embeds=None, positions=None, cache=None,
-                         ctx: Optional[ShardingCtx] = None):
+                         ctx: Optional[ShardingCtx] = None,
+                         kernel_mode: str = "ref"):
     """One-token decode step against a paged KV pool.
 
     ``cache`` is an :func:`repro.models.cache.init_paged_cache` pytree:
     attention layers hold shared page arrays plus per-slot block tables;
     SSM layers keep their per-slot state.  New tokens are written in
     place into their pages (O(B) scatter) and attention reads through
-    the block table.  Returns (logits, new_cache).
+    the block table in ``kernel_mode`` (see ``kernels.ops``).  Returns
+    (logits, new_cache).
     """
     assert cache is not None
     x = embeds if embeds is not None else embed_tokens(params, cfg, tokens)
@@ -291,7 +296,7 @@ def forward_decode_paged(params, cfg: ModelConfig, *, tokens=None,
         else:
             h, new_lc = attn.gqa_decode_paged(
                 block["attn"], cfg, xin, positions, layer_cache,
-                block_tables, cache_pos)
+                block_tables, cache_pos, kernel_mode=kernel_mode)
         x = x + h
         y = apply_norm(x, block["mlp_norm"], cfg)
         if "moe" in block:

@@ -18,13 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax.shard_map landed in newer releases; older jax ships it under
-# jax.experimental with a kwargs-compatible signature
-try:
-    _shard_map = jax.shard_map
-except AttributeError:                                    # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 class ShardingCtx(NamedTuple):
     """Mesh context threaded through model forward passes."""
@@ -201,7 +194,7 @@ def moe_ffn(params, cfg, x, ctx: Optional[ShardingCtx] = None):
                     aux = jax.lax.pmean(aux, pmean_axes)
                 return out.reshape(bb, ss, d), aux
 
-            out, aux = _shard_map(
+            out, aux = jax.shard_map(
                 smbody_ep, mesh=mesh,
                 in_specs=(xspec, P(None, None), wspec_gu, wspec_gu, wspec_d),
                 out_specs=(xspec, P()),
@@ -218,7 +211,7 @@ def moe_ffn(params, cfg, x, ctx: Optional[ShardingCtx] = None):
                     aux = jax.lax.pmean(aux, pmean_axes)
                 return out, aux
 
-            out, aux = _shard_map(
+            out, aux = jax.shard_map(
                 smbody, mesh=mesh,
                 in_specs=(xspec, rep2, rep3, rep3, rep3),
                 out_specs=(xspec, P()),

@@ -185,7 +185,11 @@ class ServeLoop:
             return self
         eng = self.eng
         if self._feed is None:
-            self._feed = jnp.zeros((eng.max_slots, 1), jnp.int32)
+            # from the first round on the feed carries the dispatch
+            # output's sharding; warming with any other would make a
+            # mesh engine compile its decode step again mid-serve
+            self._feed = eng.replicated(
+                jnp.zeros((eng.max_slots, 1), jnp.int32))
         widths = {eng.max_slots}
         if self.bucket_batches:
             w = 1

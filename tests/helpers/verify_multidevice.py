@@ -10,7 +10,9 @@ cfg = ModelConfig(name="m", family="moe", num_layers=1, d_model=64,
 params = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 64), jnp.float32)
 out_ref, aux_ref = moe_ffn(params, cfg, x, None)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 ctx = ShardingCtx(mesh=mesh, dp_axes=("data",), tp_axis="model", expert_parallel=True)
 out_ep, aux_ep = jax.jit(lambda p, xx: moe_ffn(p, cfg, xx, ctx))(params, x)
 err = float(jnp.max(jnp.abs(out_ep - out_ref)))

@@ -94,7 +94,7 @@ def check_logits_parity():
     # 1/8 of the kv heads; the block tables stay fully replicated
     k = c8["layers"][0]["k"]
     shard_shape = k.addressable_shards[0].data.shape
-    assert shard_shape[2] * 8 == k.shape[2], (shard_shape, k.shape)
+    assert shard_shape[0] * 8 == k.shape[0], (shard_shape, k.shape)
     bt8 = c8["block_tables"]
     assert bt8.addressable_shards[0].data.shape == bt8.shape
 
@@ -144,7 +144,7 @@ def check_engine_parity():
         tp = Engine(CFG, PARAMS, max_slots=4, max_seq_len=128,
                     chunked_prefill=chunk, mesh=MESH)
         assert tp.cache["layers"][0]["k"].addressable_shards[0] \
-            .data.shape[2] * 8 == CFG.num_kv_heads
+            .data.shape[0] * 8 == CFG.num_kv_heads
         # shared prefix exercises aliasing + CoW under the mesh
         r_ref = ref.run_fcfs(mk_requests(seed=3, shared_prefix=24))
         assert pool_ok(tp)
@@ -189,7 +189,7 @@ def check_cow_under_mesh():
     g_tp, eng = split(MESH)
     assert g_ref == g_tp, (g_ref, g_tp)
     k = eng.cache["layers"][0]["k"]
-    assert k.addressable_shards[0].data.shape[2] * 8 == k.shape[2]
+    assert k.addressable_shards[0].data.shape[0] * 8 == k.shape[0]
     print("copy-on-write page split OK under mesh sharding")
 
 

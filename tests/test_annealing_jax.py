@@ -10,7 +10,6 @@ import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental import enable_x64  # noqa: E402
 
 import repro.core.annealing_jax as aj  # noqa: E402
 from repro.core import PAPER_TABLE2, SAParams, as_arrays, evaluate  # noqa: E402
@@ -82,7 +81,7 @@ def test_incremental_scorer_matches_oracle_to_1e6(jitted, regime):
     and the numpy ``evaluate`` oracle (exact in x64), the met counts
     agree exactly, and no row goes stale (rebuild-from-scratch parity).
     """
-    with enable_x64():
+    with jax.enable_x64(True):
         for seed in (0, 1):
             arrays = _arrays(seed, regime=regime)
             reqc = aj._pack(arrays, PAPER_TABLE2, aj._pad_len(N))
@@ -123,7 +122,7 @@ def test_incremental_scorer_matches_oracle_to_1e6(jitted, regime):
 def test_scorer_padding_invariance():
     """Masked padding must not change the objective: the same instance
     packed at two pad lengths scores identically (and equals numpy)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         arrays = _arrays(3)
         p0, b0 = fcfs_schedule(N, MB)
         for pad in (16, 32):

@@ -162,22 +162,15 @@ def test_decode_attention_q8(b, h, kv, hd, L, bk):
 def test_model_forward_via_pallas_kernels():
     """forward_full routed through the Pallas flash-attention kernel
     (interpret mode) matches the XLA einsum path."""
-    from repro.kernels import ops as kops
     from repro.models import ModelConfig, forward_full, init_params
-    from repro.models.attention import set_attention_kernels
     cfg = ModelConfig(name="kd", family="dense", num_layers=2, d_model=64,
                       num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=97,
                       dtype="float32", sliding_window=24)
     params = init_params(jax.random.PRNGKey(0), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 97)
     want, _, _ = forward_full(params, cfg, tokens=toks)
-    kops.set_kernel_mode("interpret")
-    set_attention_kernels(True)
-    try:
-        got, _, _ = forward_full(params, cfg, tokens=toks)
-    finally:
-        set_attention_kernels(False)
-        kops.set_kernel_mode("auto")
+    got, _, _ = forward_full(params, cfg, tokens=toks,
+                             attn_kernel="interpret")
     np.testing.assert_allclose(got, want, atol=2e-3, rtol=2e-3)
 
 

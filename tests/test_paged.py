@@ -43,6 +43,11 @@ def params():
     return init_params(jax.random.PRNGKey(0), CFG)
 
 
+def _kv_major(pages):
+    """[N, P, KV, d] -> the pool's kv-head-major [KV, N, P, d]."""
+    return jnp.moveaxis(pages, 2, 0)
+
+
 # ------------------------------------------------------------ kernel level
 @pytest.mark.parametrize("b,h,kv,hd,P,npg,window", [
     (1, 4, 4, 32, 16, 4, 0),       # MHA
@@ -57,8 +62,8 @@ def test_paged_kernel_matches_ref(b, h, kv, hd, P, npg, window):
     nb = 1 + b * npg
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (b, h, hd), jnp.float32)
-    kp = jax.random.normal(ks[1], (nb, P, kv, hd), jnp.float32)
-    vp = jax.random.normal(ks[2], (nb, P, kv, hd), jnp.float32)
+    kp = _kv_major(jax.random.normal(ks[1], (nb, P, kv, hd), jnp.float32))
+    vp = _kv_major(jax.random.normal(ks[2], (nb, P, kv, hd), jnp.float32))
     bt = jnp.arange(1, 1 + b * npg, dtype=jnp.int32).reshape(b, npg)
     lengths = jnp.asarray(
         np.linspace(3, L + P, b).astype(np.int32))     # incl. wrapped
@@ -83,7 +88,8 @@ def test_paged_kernel_matches_dense_kernel_ref():
     kc = kp[bt].reshape(b, L, kv, hd)
     vc = vp[bt].reshape(b, L, kv, hd)
     want = ref.decode_attention_ref(q, kc, vc, lengths)
-    got = ref.decode_attention_paged_ref(q, kp, vp, bt, lengths)
+    got = ref.decode_attention_paged_ref(q, _kv_major(kp), _kv_major(vp),
+                                         bt, lengths)
     np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-6)
 
 
@@ -93,8 +99,7 @@ def test_paged_kernel_q8_matches_ref():
     q = jax.random.normal(ks[0], (b, h, hd), jnp.float32)
     kf = jax.random.normal(ks[1], (1 + b * npg, P, kv, hd), jnp.float32)
     vf = jax.random.normal(ks[2], (1 + b * npg, P, kv, hd), jnp.float32)
-    kq, ksc = quantize_kv(kf)
-    vq, vsc = quantize_kv(vf)
+    kq, ksc, vq, vsc = map(_kv_major, quantize_kv(kf) + quantize_kv(vf))
     bt = jnp.arange(1, 1 + b * npg, dtype=jnp.int32).reshape(b, npg)
     lengths = jnp.array([17, P * npg], jnp.int32)
     out = decode_attention_paged_q8(q, kq, ksc, vq, vsc, bt, lengths,
@@ -152,6 +157,38 @@ def test_paged_engine_matches_dense_engine(params):
     b = Engine(CFG, params, max_slots=3, max_seq_len=128,
                paged=False).run_fcfs(_rts(5, seed=3))
     assert all(a[i]["tokens"] == b[i]["tokens"] for i in a)
+
+
+def test_engine_kernel_mode_is_per_engine(params):
+    """Each engine closes over its own kernel mode: an engine decoding
+    through the (interpreted) Pallas paged kernel generates the same
+    greedy tokens as one on the gather reference, built after it in the
+    same process, and neither changes the other's declared mode."""
+    a = Engine(CFG, params, max_slots=3, max_seq_len=128,
+               kernel_mode="interpret")
+    b = Engine(CFG, params, max_slots=3, max_seq_len=128, kernel_mode="ref")
+    ra = a.run_fcfs(_rts(4, seed=11))
+    rb = b.run_fcfs(_rts(4, seed=11))
+    assert (a.kernel_mode, b.kernel_mode) == ("interpret", "ref")
+    assert all(ra[i]["tokens"] == rb[i]["tokens"] for i in ra)
+
+
+@pytest.mark.parametrize("mode,tp,want", [
+    ("interpret", 1, "interpret"),
+    ("ref", 1, "ref"),
+    (None, 1, "ref"),              # no TPU backend in the tests
+    (None, 4, "ref"),              # tp > 1 declares the gather reference
+    ("ref", 4, "ref"),
+    ("pallas", 4, ValueError),     # the kernel is not partitioned yet
+    ("auto", 1, ValueError),       # no such mode
+])
+def test_resolve_kernel_mode(mode, tp, want):
+    from repro.kernels.ops import resolve_mode
+    if want is ValueError:
+        with pytest.raises(ValueError):
+            resolve_mode(mode, tp)
+    else:
+        assert resolve_mode(mode, tp) == want
 
 
 def test_paged_engine_chunked_matches_dense(params):

@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 import repro.core.annealing_jax as aj
 from repro.core.latency_model import PAPER_TABLE2
@@ -241,12 +240,12 @@ def test_aliased_prefix_logits_match_dense(params):
 def test_copy_page_copies_every_attention_layer(params):
     cache = init_paged_cache(CFG, 1, 128, 8, P)
     k0 = cache["layers"][0]["k"]
-    cache["layers"][0]["k"] = k0.at[2].set(1.5)
+    cache["layers"][0]["k"] = k0.at[:, 2].set(1.5)   # kv-head-major
     cache = copy_page(cache, 2, 5)
     for layer in cache["layers"]:
         for v in layer.values():
-            np.testing.assert_array_equal(np.asarray(v[5]),
-                                          np.asarray(v[2]))
+            np.testing.assert_array_equal(np.asarray(v[:, 5]),
+                                          np.asarray(v[:, 2]))
 
 
 # ------------------------------------------------------------ engine level
@@ -429,7 +428,7 @@ def test_annealer_backends_price_cached_prefix_identically():
     g_np = calculate_g(arrays, PAPER_TABLE2, perm, bid)
     bnd = np.zeros(n, np.int32)
     bnd[np.searchsorted(bid, np.unique(bid))] = 1
-    with enable_x64():
+    with jax.enable_x64(True):
         reqc = aj._pack(arrays, PAPER_TABLE2, n)
         g_jax, _ = aj._eval_g(reqc, jnp.asarray(perm, jnp.int32),
                               jnp.asarray(bnd, jnp.int32))

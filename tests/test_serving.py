@@ -269,3 +269,49 @@ def test_serve_workload_trace_replay(params):
     assert len(res) == 4
     for v in res.values():
         assert "met_wall" in v and v["tokens"]
+
+
+# ------------------------------------------------------------ launcher
+def test_fit_latency_model_fits_or_refuses(params):
+    """The launcher's fit uses this device's samples: enough warm-up
+    requests give a fitted model, too few raise (no silent fallback to
+    the paper's V100 coefficients)."""
+    from repro.core.latency_model import PAPER_TABLE2
+    from repro.launch.serve import fit_latency_model
+    with pytest.raises(RuntimeError, match="more samples"):
+        fit_latency_model(CFG, params, 2, np.random.default_rng(0),
+                          n_warm=3)
+    model = fit_latency_model(CFG, params, 2, np.random.default_rng(0))
+    assert isinstance(model, LinearLatencyModel) and model != PAPER_TABLE2
+
+
+@pytest.mark.parametrize("n", [1, 3])
+def test_fleet_replicas_placed_per_device(params, n, capsys):
+    """Replica i lives on jax.devices()[i % count] — params and cache
+    both — and sharing a device is said out loud."""
+    from repro.launch.serve import make_fleet_engines
+    engines = make_fleet_engines(CFG, params, n, max_slots=2,
+                                 max_seq_len=64)
+    devs = jax.devices()
+    for i, eng in enumerate(engines):
+        homes = {d for leaf in jax.tree.leaves((eng.params, eng.cache))
+                 for d in leaf.devices()}
+        assert homes == {devs[i % len(devs)]}
+    shared = n > len(devs)
+    assert ("share" in capsys.readouterr().out) == shared
+
+
+def test_mesh_engine_serves_without_recompiling_dispatch(params):
+    """The warm-up feed carries the dispatch output's sharding, so a
+    mesh-placed engine (a fleet replica) compiles no decode step while
+    serving — every width it needs was compiled by ``start``."""
+    from repro.launch.mesh import make_host_mesh
+    eng = _engine(params, mesh=make_host_mesh(tp=1))
+    loop = ServeLoop(eng, "fcfs")
+    prompts = _prompts(4, seed=7)
+    loop.start(warm_lengths=[len(p) for p in prompts])
+    warmed = eng._dispatch_fn._cache_size()
+    streams = [loop.submit(p, max_new_tokens=5) for p in prompts]
+    loop.serve()
+    assert all(len(s.tokens) == 5 for s in streams)
+    assert eng._dispatch_fn._cache_size() == warmed

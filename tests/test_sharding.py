@@ -10,7 +10,7 @@ import pytest
 # tests need no devices, and the sharded-execution tests use a 1x1 mesh.
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import ASSIGNED, get_config, get_reduced
 from repro.distributed.sharding import (ParallelismConfig, cache_specs,
@@ -21,7 +21,8 @@ from repro.models.cache import cache_spec as cache_sds
 
 
 def _mesh_1x1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 @pytest.mark.parametrize("arch", ASSIGNED)
@@ -129,9 +130,9 @@ def test_paged_cache_specs_head_sharding():
     assert specs["block_tables"] == P(None, None)
     assert specs["pos"] == P(None)
     for lyr in specs["layers"]:
-        # [num_blocks, page, kv_heads, head_dim] — heads on "model"
-        assert lyr["k"] == P(None, None, "model", None)
-        assert lyr["v"] == P(None, None, "model", None)
+        # [kv_heads, num_blocks, page, head_dim] — heads on "model"
+        assert lyr["k"] == P("model", None, None, None)
+        assert lyr["v"] == P("model", None, None, None)
 
 
 def test_paged_cache_specs_indivisible_heads_replicate():
