@@ -9,7 +9,7 @@ Run from the root of a checkout, on a machine with a TPU:
 One chip: the paged-decode kernel mode must resolve to ``pallas``; one
 full-width decode step through the compiled paged kernel is compared
 with the jnp gather reference; the latency model is fitted on the chip;
-then about eight requests are served through ``ServeLoop`` under
+then eight requests are served through ``ServeLoop`` under
 ``slo-reanneal`` and each must complete with its full token budget.
 
 ``--chips 4``: qwen3-1.7b on a tp=4 mesh against a tp=1 engine on one of
@@ -36,6 +36,7 @@ ROOT = Path(__file__).resolve().parent
 ARCH = "qwen3-1.7b"
 KERNEL_MODE = "pallas"          # what the paged decode must resolve to
 MAX_SEQ_LEN = 256
+N_REQUESTS = 8                  # served by each phase, one chip or four
 BLOCK_SIZE = 16
 # bf16 tolerance of the kernel-vs-reference attention output (the bound
 # tests/test_kernels.py holds bf16 kernels to): |d| <= ATOL + RTOL*|ref|
@@ -183,7 +184,7 @@ def kernel_parity(cfg, params, rng, clock):
         check(rel <= LOGITS_REL_TOL, "decode-step logits differ")
 
 
-def serve_one_chip(cfg, params, rng, clock, n_requests):
+def serve_one_chip(cfg, params, rng, clock):
     from repro.data.synthetic import sample_serve_workload
     from repro.engine.engine import Engine
     from repro.launch.serve import fit_latency_model
@@ -193,32 +194,38 @@ def serve_one_chip(cfg, params, rng, clock, n_requests):
         model = fit_latency_model(cfg, params, max_batch, rng)
         print("   fitted (s): " + " ".join(
             f"{k}={float(v):.4g}" for k, v in vars(model).items()))
-    with Phase(f"serve {n_requests} requests (ServeLoop, slo-reanneal)",
+    with Phase(f"serve {N_REQUESTS} requests (ServeLoop, slo-reanneal)",
                clock):
         eng = Engine(cfg, params, max_slots=max_batch,
                      max_seq_len=MAX_SEQ_LEN, block_size=BLOCK_SIZE)
         print(f"   engine kernel_mode={eng.kernel_mode}")
         check(eng.kernel_mode == KERNEL_MODE,
               f"kernel_mode {eng.kernel_mode}, want {KERNEL_MODE}")
-        pairs = sample_serve_workload(n_requests, cfg.vocab_size, rng=rng,
+        pairs = sample_serve_workload(N_REQUESTS, cfg.vocab_size, rng=rng,
                                       arrival_rate=4.0)
         loop = ServeLoop(eng, "slo-reanneal", model=model)
-        warm_and_serve(loop, pairs, clock)
+        warm_and_serve(loop, [eng], pairs, clock)
 
 
-def warm_and_serve(loop, pairs, clock):
-    """Compile-warm a ServeLoop or EngineFleet, serve ``pairs``, check
-    every request got its full budget, print the metrics summary."""
+def warm_and_serve(loop, engines, pairs, clock):
+    """Compile-warm a ServeLoop or EngineFleet over ``engines``, serve
+    ``pairs``, check every request got its full budget and no engine
+    compiled a decode step while serving, print the metrics summary."""
     t, c = time.perf_counter(), clock.seconds
     loop.start(warm_lengths=[len(p) for _, p in pairs])
     print(f"   warm-up: wall_s={time.perf_counter() - t:.2f} "
           f"compile_s={clock.seconds - c:.2f}")
+    warmed = [eng._dispatch_fn._cache_size() for eng in engines]
     streams = loop.submit_trace(pairs)
     t, c = time.perf_counter(), clock.seconds
     loop.serve()
     print(f"   serve: wall_s={time.perf_counter() - t:.2f} "
           f"compile_s={clock.seconds - c:.2f}")
     check_streams(pairs, streams)
+    served = [eng._dispatch_fn._cache_size() for eng in engines]
+    print(f"   decode-step executables per engine: after warm-up {warmed}, "
+          f"after serving {served}")
+    check(served == warmed, "an engine compiled a decode step while serving")
     summary = loop.metrics.summary()
     print("   smoke reading, not a benchmark: "
           + json.dumps({k: summary[k] for k in sorted(summary)}))
@@ -279,12 +286,12 @@ def tp_parity(cfg, params, rng, clock, devs):
             check(rel <= LOGITS_REL_TOL, f"tp=4 {what} logits differ")
 
 
-def fleet_four_chips(cfg, params, rng, clock, n_requests):
+def fleet_four_chips(cfg, params, rng, clock):
     import jax
     from repro.data.synthetic import sample_serve_workload
     from repro.launch.serve import make_fleet_engines
     from repro.serving import EngineFleet
-    with Phase(f"4-replica fleet, {n_requests} requests", clock):
+    with Phase(f"4-replica fleet, {N_REQUESTS} requests", clock):
         engines = make_fleet_engines(cfg, params, 4, max_slots=2,
                                      max_seq_len=MAX_SEQ_LEN,
                                      block_size=BLOCK_SIZE)
@@ -298,18 +305,17 @@ def fleet_four_chips(cfg, params, rng, clock, n_requests):
                   f"kernel_mode={eng.kernel_mode}")
         check(len(set(homes)) == 4, f"replicas share devices: {homes}")
         # prompts in one prefill bucket (64): fewer per-replica compiles
-        pairs = sample_serve_workload(n_requests, cfg.vocab_size, rng=rng,
+        pairs = sample_serve_workload(N_REQUESTS, cfg.vocab_size, rng=rng,
                                       arrival_rate=8.0, in_range=(33, 64),
                                       out_range=(8, 24))
         warm_and_serve(EngineFleet(engines, "fcfs", mapper="round-robin"),
-                       pairs, clock)
+                       engines, pairs, clock)
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--requests", type=int, default=8)
     args = ap.parse_args()
 
     import jax
@@ -328,7 +334,7 @@ def main() -> int:
     from repro.launch.compile_cache import setup_compile_cache
     from repro.models import init_params
 
-    cache_dir = setup_compile_cache(ROOT)
+    cache_dir = setup_compile_cache()
     clock = CompileClock(jax)
     print(f"device_kind={devs[0].device_kind} device_count={len(devs)} "
           f"jax={jax.__version__} compile_cache={cache_dir}")
@@ -343,10 +349,10 @@ def main() -> int:
               f"params={n / 1e9:.3f}B ({nbytes / 2**30:.2f} GiB {cfg.dtype})")
     if args.chips == 1:
         kernel_parity(cfg, params, rng, clock)
-        serve_one_chip(cfg, params, rng, clock, args.requests)
+        serve_one_chip(cfg, params, rng, clock)
     else:
         tp_parity(cfg, params, rng, clock, devs)
-        fleet_four_chips(cfg, params, rng, clock, args.requests)
+        fleet_four_chips(cfg, params, rng, clock)
     print(f"compile_s_total={clock.seconds:.2f} compiles={clock.count} "
           f"persistent_cache_hits={clock.cache_hits}")
     print(json.dumps({"ok": True, "device": {

@@ -128,10 +128,11 @@ def _safe_tables(block_tables, lengths, block_size, num_blocks):
     return jnp.where(live, bt, 0).astype(jnp.int32)
 
 
-def _paged_call(kernel, q, pages, block_tables, lengths, window, interpret):
-    """Build and run the paged pallas_call.  ``pages`` is the kernel's
-    page-array operands in order (k, v or k, k_scale, v, v_scale), all
-    ``[kv, N, P, last]``."""
+def _paged_call(kernel, name, q, pages, block_tables, lengths, window,
+                interpret):
+    """Build and run the paged pallas_call, named ``name`` in traces.
+    ``pages`` is the kernel's page-array operands in order (k, v or k,
+    k_scale, v, v_scale), all ``[kv, N, P, last]``."""
     b, h, hd = q.shape
     kv, n_blocks, P = pages[0].shape[:3]
     num_pages = block_tables.shape[1]
@@ -163,7 +164,7 @@ def _paged_call(kernel, q, pages, block_tables, lengths, window, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
-        name="decode_attention_paged",
+        name=name,
     )(bt, lengths, qg, *pages)
     return out.reshape(b, h, hd)
 
@@ -174,8 +175,9 @@ def decode_attention_paged(q, k_pages, v_pages, block_tables, lengths, *,
     """q: [B,H,hd]; pages: [KV,N,P,hd]; block_tables: [B,pages_per_seq]
     int32; lengths: [B] int32 (context length incl. the current token)
     -> [B,H,hd]."""
-    return _paged_call(_kernel, q, (k_pages, v_pages), block_tables,
-                       lengths, window, interpret)
+    return _paged_call(_kernel, "decode_attention_paged", q,
+                       (k_pages, v_pages), block_tables, lengths, window,
+                       interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -184,5 +186,6 @@ def decode_attention_paged_q8(q, k_pages, k_scale, v_pages, v_scale,
                               interpret: bool = False):
     """int8 pages [KV,N,P,hd] + bf16 scales [KV,N,P,1]; else as
     :func:`decode_attention_paged`."""
-    return _paged_call(_kernel_q8, q, (k_pages, k_scale, v_pages, v_scale),
-                       block_tables, lengths, window, interpret)
+    return _paged_call(_kernel_q8, "decode_attention_paged_q8", q,
+                       (k_pages, k_scale, v_pages, v_scale), block_tables,
+                       lengths, window, interpret)

@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import os
 from pathlib import Path
-from typing import Optional
 
 import jax
 
@@ -22,14 +21,11 @@ ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
 
 
-def setup_compile_cache(root: Optional[os.PathLike] = None) -> str:
-    """Turn the persistent compilation cache on; return its directory.
-
-    ``root`` overrides the checkout root the fallback directory
-    (``<root>/.jax_cache``) lives under."""
+def setup_compile_cache() -> str:
+    """Turn the persistent compilation cache on; return its directory."""
     env = os.environ.get(ENV_VAR)
     if env:
         return env
-    path = str(Path(root or CHECKOUT_ROOT) / ".jax_cache")
+    path = str(CHECKOUT_ROOT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -21,7 +21,8 @@ serving ticks alongside running decode dispatches (chunk-as-tick).
 (least-loaded default; ``annealed`` runs the paper's Algorithm 2 as the
 routing plan — see docs/sharding.md); replica *i* runs on
 ``jax.devices()[i]``, and when N exceeds the device count replicas share
-devices round-robin.
+devices round-robin (an arch with no attention layers keeps every
+replica on the default device).
 """
 from __future__ import annotations
 
@@ -74,8 +75,14 @@ def make_fleet_engines(cfg, params, n: int, **engine_kw):
     """``n`` engines, replica ``i`` committed to ``jax.devices()[i]`` by
     a one-device mesh; past the device count, replicas share devices
     round-robin (``i % count``) — said out loud, since shared devices
-    serialize."""
+    serialize.  An arch with no attention layers runs the dense engine,
+    which takes no mesh: its replicas all share the default device."""
     devs = jax.devices()
+    if not cfg.attn_layers:
+        print(f"{n} replicas share the default device {devs[0].id}: "
+              f"{cfg.name} has no attention layers, so no paged KV pool "
+              "to place")
+        return [Engine(cfg, params, **engine_kw) for _ in range(n)]
     if n > len(devs):
         print(f"{n} replicas share {len(devs)} device(s): replica i runs "
               f"on device i % {len(devs)}")
@@ -149,9 +156,6 @@ def main():
     planner = args.policy in ("slo", "planned")
     mode = args.mode
     fleet = mode == "stream" and not planner and args.instances > 1
-    if fleet and not cfg.attn_layers:
-        raise SystemExit("--instances places replicas through the paged "
-                         "KV layout, which needs attention layers")
     engines = make_fleet_engines(cfg, params, args.instances,
                                  max_slots=args.max_batch, max_seq_len=256) \
         if fleet else [Engine(cfg, params, max_slots=args.max_batch,

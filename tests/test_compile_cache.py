@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 
+from repro.launch import compile_cache
 from repro.launch.compile_cache import (CHECKOUT_ROOT, ENV_VAR,
                                         setup_compile_cache)
 
@@ -34,8 +35,9 @@ def test_env_dir_wins_and_nothing_else_is_set(tmp_path, monkeypatch,
                                               restore_cache_config):
     env_dir = tmp_path / "from_env"
     monkeypatch.setenv(ENV_VAR, str(env_dir))
+    monkeypatch.setattr(compile_cache, "CHECKOUT_ROOT", tmp_path / "checkout")
     before = jax.config.jax_compilation_cache_dir
-    assert setup_compile_cache(tmp_path / "checkout") == str(env_dir)
+    assert setup_compile_cache() == str(env_dir)
     assert jax.config.jax_compilation_cache_dir == before
     assert not (tmp_path / "checkout").exists()
 
@@ -43,9 +45,10 @@ def test_env_dir_wins_and_nothing_else_is_set(tmp_path, monkeypatch,
 def test_unset_env_uses_fixed_dir_in_checkout(tmp_path, monkeypatch,
                                               restore_cache_config):
     monkeypatch.delenv(ENV_VAR, raising=False)
-    path = setup_compile_cache(tmp_path)
+    monkeypatch.setattr(compile_cache, "CHECKOUT_ROOT", tmp_path)
+    path = setup_compile_cache()
     assert path == str(tmp_path / ".jax_cache")
-    assert setup_compile_cache(tmp_path) == path        # never moves
+    assert setup_compile_cache() == path                # never moves
     _compile_something()
     assert os.listdir(path), "no cache entry written"
     assert os.listdir(tmp_path) == [".jax_cache"]

@@ -301,6 +301,21 @@ def test_fleet_replicas_placed_per_device(params, n, capsys):
     assert ("share" in capsys.readouterr().out) == shared
 
 
+def test_serve_cli_fleet_of_ssm_replicas(monkeypatch, capsys):
+    """``serve --instances 2`` on an arch with no attention layers still
+    serves: the dense replicas share the default device, said out loud."""
+    import sys
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "setup_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "mamba2-780m", "--reduced", "--n", "3",
+        "--policy", "fcfs", "--instances", "2"])
+    serve.main()
+    out = capsys.readouterr().out
+    assert "2 replicas share the default device" in out
+    assert "mode=fleet2:least-loaded" in out and "attainment=" in out
+
+
 def test_mesh_engine_serves_without_recompiling_dispatch(params):
     """The warm-up feed carries the dispatch output's sharding, so a
     mesh-placed engine (a fleet replica) compiles no decode step while

@@ -5,6 +5,7 @@ kernel (block tiling, VMEM scratch layout), which interpret-mode tests
 cannot show.  The topology is described inside a fixture so that no
 module import loads the TPU compiler library."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,7 +69,16 @@ def test_paged_decode_compiles_for_v5e(one_chip, arch, block_size,
     fn = decode_attention_paged_q8 if quantized else decode_attention_paged
     compiled = jax.jit(fn).lower(
         *_args(one_chip, arch, block_size, quantized)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    # each kernel carries its own name into the trace
+    assert _kernel_names(compiled.as_text()) == {fn.__name__}
+
+
+def _kernel_names(hlo):
+    """Instruction names of the Mosaic custom calls, minus the ``.N``
+    suffix XLA adds."""
+    return {re.match(r"\s*%([\w-]+?)(\.\d+)? = ", line).group(1)
+            for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
 
 
 def test_windowed_paged_decode_compiles_for_v5e(one_chip):
