@@ -1,0 +1,156 @@
+"""From a profiler trace (``.xplane.pb``) to device busy and idle time,
+device time per operation and per program, host spans, and idle gaps
+labelled by the host span open during them.
+
+Device planes are ``/device:TPU:<n>``.  Their ``XLA Ops`` line holds one
+event per operation, named by its HLO text (``%decode_attention_paged.81
+= bf16[...] custom-call(...)``), which :func:`op_name` folds to the
+instruction's base name (``decode_attention_paged``: a Pallas kernel
+appears under the name it was given); their ``XLA Modules`` line holds
+one event per run of a compiled program
+(``jit__decode_dispatch_paged(...)``).
+Host spans are the ``TraceAnnotation`` events the harness writes on the
+host threads' lines.  All times are nanoseconds on the trace's one clock.
+"""
+from __future__ import annotations
+
+import dataclasses
+import re
+from collections import defaultdict
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+
+Interval = Tuple[int, int]          # (start_ns, end_ns)
+
+
+@dataclasses.dataclass
+class Event:
+    name: str
+    start: int
+    end: int
+
+    @property
+    def dur(self) -> int:
+        return self.end - self.start
+
+
+@dataclasses.dataclass
+class Trace:
+    ops: Dict[str, List[Event]]        # device plane -> operations
+    modules: Dict[str, List[Event]]    # device plane -> program runs
+    spans: List[Event]                 # host spans (any thread)
+
+    @property
+    def devices(self) -> List[str]:
+        return sorted(self.ops)
+
+
+def load(path: str, span_prefix: str = "bench.") -> Trace:
+    """Read the planes this reduction needs from an ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+    pd = ProfileData.from_file(path)
+    ops: Dict[str, List[Event]] = {}
+    modules: Dict[str, List[Event]] = {}
+    spans: List[Event] = []
+    for plane in pd.planes:
+        name = plane.name
+        if name.startswith("/device:TPU:"):
+            for line in plane.lines:
+                evs = [Event(e.name, int(e.start_ns),
+                             int(e.start_ns + e.duration_ns))
+                       for e in line.events]
+                if line.name == "XLA Ops":
+                    for e in evs:
+                        e.name = op_name(e.name)
+                    ops[name] = evs
+                elif line.name == "XLA Modules":
+                    modules[name] = evs
+        elif name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(span_prefix):
+                        spans.append(Event(e.name, int(e.start_ns),
+                                           int(e.start_ns + e.duration_ns)))
+    return Trace(ops=ops, modules=modules, spans=spans)
+
+
+# ------------------------------------------------------------- intervals
+def union(intervals: Iterable[Interval]) -> List[Interval]:
+    out: List[List[int]] = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return [(s, e) for s, e in out]
+
+
+def clip(intervals: Iterable[Interval], lo: int, hi: int) -> List[Interval]:
+    return [(max(s, lo), min(e, hi)) for s, e in intervals
+            if e > lo and s < hi]
+
+
+def total(intervals: Iterable[Interval]) -> int:
+    return sum(e - s for s, e in intervals)
+
+
+def busy(events: Sequence[Event], lo: int, hi: int) -> List[Interval]:
+    """Union of the events' intervals inside ``[lo, hi)``."""
+    return union(clip(((e.start, e.end) for e in events), lo, hi))
+
+
+def gaps(busy_iv: Sequence[Interval], lo: int, hi: int) -> List[Interval]:
+    """The complement of ``busy_iv`` inside ``[lo, hi)``."""
+    out, t = [], lo
+    for s, e in busy_iv:
+        if s > t:
+            out.append((t, s))
+        t = max(t, e)
+    if t < hi:
+        out.append((t, hi))
+    return out
+
+
+# ---------------------------------------------------------- attribution
+def time_by_name(events: Sequence[Event], lo: int, hi: int,
+                 key=lambda n: n) -> Dict[str, int]:
+    """Device nanoseconds per name (``key`` folds names together),
+    counting each event's part inside ``[lo, hi)``."""
+    out: Dict[str, int] = defaultdict(int)
+    for e in events:
+        s, t = max(e.start, lo), min(e.end, hi)
+        if t > s:
+            out[key(e.name)] += t - s
+    return dict(out)
+
+
+def span_at(spans: Sequence[Event], s: int, e: int) -> Optional[str]:
+    """The innermost (shortest) host span that overlaps ``[s, e)`` the
+    most; None where no span is open."""
+    best, best_key = None, None
+    for sp in spans:
+        ov = min(sp.end, e) - max(sp.start, s)
+        if ov <= 0:
+            continue
+        k = (ov, -sp.dur)
+        if best_key is None or k > best_key:
+            best, best_key = sp.name, k
+    return best
+
+
+def labelled_gaps(gap_iv: Sequence[Interval], spans: Sequence[Event],
+                  top: int = 10) -> List[Tuple[str, int]]:
+    """The ``top`` longest gaps, each named by the host span open in it
+    (``"<none>"`` where the host was in no span)."""
+    longest = sorted(gap_iv, key=lambda g: g[0] - g[1])[:top]
+    return [(span_at(spans, s, e) or "<none>", e - s) for s, e in longest]
+
+
+def op_name(name: str) -> str:
+    """``%fusion.16 = bf16[...] ...`` -> ``fusion``: the HLO instruction
+    name without its text or its ``.N`` suffix."""
+    return re.sub(r"\.\d+$", "", name.split(" = ", 1)[0].lstrip("%"))
+
+
+def module_name(name: str) -> str:
+    """``jit__decode_dispatch_paged(123)`` -> ``jit__decode_dispatch_paged``."""
+    return name.split("(", 1)[0]
