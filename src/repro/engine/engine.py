@@ -45,6 +45,7 @@ the paper's linear latency model can be fit from *this* engine's behaviour
 """
 from __future__ import annotations
 
+import contextlib
 import time
 import warnings
 from functools import partial
@@ -53,6 +54,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -199,6 +201,10 @@ class Engine:
         self._partial: Dict[int, object] = {}
         self._warm = set()
         self.cow_copies = 0          # copy-on-write page splits performed
+        # warm-path entries (compiles or warm runs while serving) and
+        # seconds the host has spent blocked on the device (sync spans)
+        self.warm_runs = 0
+        self.sync_s = 0.0
         # fused decode+sample dispatch path (serving loop): one jit, one
         # compilation per pow-2 batch width; donation only in paged mode
         # (the dense step merges with `where`, allocating fresh arrays)
@@ -264,6 +270,25 @@ class Engine:
         if getattr(self, "mesh", None) is None:
             return cache
         return jax.device_put(cache, self._cache_shardings)
+
+    # ------------------------------------------------------------- spans
+    def _warm_span(self, program: str, length: int):
+        """Span and count of one warm-path entry: a compile (or warm
+        run) of ``program`` at ``length`` on the serving path."""
+        self.warm_runs += 1
+        return TraceAnnotation("engine.warm", program=program,
+                               length=length)
+
+    @contextlib.contextmanager
+    def sync_span(self, name: str = "engine.sync"):
+        """Span of a host wait on the device; its seconds add to
+        ``sync_s``."""
+        t0 = time.perf_counter()
+        try:
+            with TraceAnnotation(name):
+                yield
+        finally:
+            self.sync_s += time.perf_counter() - t0
 
     # ------------------------------------------------------------ jitted
     def _decode_step(self, params, cache, tokens, active):
@@ -366,12 +391,16 @@ class Engine:
         if width == self.max_slots:
             logits, new_cache = self._decode_step_paged(params, cache,
                                                         tokens, active)
-            return sample(logits, key, self.temperature), new_cache
-        sub = self._slice_slots(cache, width)
+            with jax.named_scope("sample"):
+                return sample(logits, key, self.temperature), new_cache
+        with jax.named_scope("slots"):
+            sub = self._slice_slots(cache, width)
         logits, new_sub = self._decode_step_paged(params, sub, tokens,
                                                   active)
-        return (sample(logits, key, self.temperature),
-                self._merge_slots(cache, new_sub, width))
+        with jax.named_scope("sample"):
+            toks = sample(logits, key, self.temperature)
+        with jax.named_scope("slots"):
+            return toks, self._merge_slots(cache, new_sub, width)
 
     def _decode_dispatch_dense(self, params, cache, tokens, active, key):
         """Dense-layout fused decode+sample (always full slot width)."""
@@ -403,21 +432,23 @@ class Engine:
             raise ValueError(f"width {width} outside (0, {self.max_slots}]")
         if any(active_np[B:]):
             raise ValueError(f"active slot >= dispatch width {B}")
-        if self.paged:
-            self._cow_guard(lookahead)
-        if ("dispatch", B) not in self._warm:
-            args = (feed[:B], jnp.zeros(B, bool),
-                    jax.random.PRNGKey(0))
+        with TraceAnnotation("engine.dispatch", width=B):
             if self.paged:
-                self._warm_paged(self._dispatch_fn, *args)
-            else:
-                jax.block_until_ready(
-                    self._dispatch_fn(self.params, self.cache, *args))
-            self._warm.add(("dispatch", B))
-        self.key, sk = jax.random.split(self.key)
-        toks, self.cache = self._dispatch_fn(
-            self.params, self.cache, feed[:B],
-            jnp.asarray(active_np[:B]), sk)
+                self._cow_guard(lookahead)
+            if ("dispatch", B) not in self._warm:
+                args = (feed[:B], jnp.zeros(B, bool),
+                        jax.random.PRNGKey(0))
+                with self._warm_span("dispatch", B):
+                    if self.paged:
+                        self._warm_paged(self._dispatch_fn, *args)
+                    else:
+                        jax.block_until_ready(self._dispatch_fn(
+                            self.params, self.cache, *args))
+                self._warm.add(("dispatch", B))
+            self.key, sk = jax.random.split(self.key)
+            toks, self.cache = self._dispatch_fn(
+                self.params, self.cache, feed[:B],
+                jnp.asarray(active_np[:B]), sk)
         return toks
 
     def finish_slot(self, rt: RuntimeRequest):
@@ -618,6 +649,8 @@ class Engine:
         rt.prefill_pos = 0
         rt.phase = Phase.PREFILLING
         rt.slot = slot
+        if rt.admit_time is None:           # preserved across preemptions
+            rt.admit_time = self.clock
         self.slot_free[slot] = False
         self.slot_req[slot] = rt
 
@@ -629,106 +662,115 @@ class Engine:
         (its logits sit at the true last context position) and flips
         the request to RUNNING, so it joins the same tick's decode
         round.  Returns True when the prefill completed."""
-        if rt.phase is not Phase.PREFILLING:
-            raise ValueError(f"request {rt.req_id} has no staged prefill")
-        slot = rt.slot
-        if self.paged and not self._slot_blocks[slot]:
-            # first step: claim the pages (aliasing any prefix indexed
-            # since admission) and skip the cached span — its aliased
-            # pages are already populated, so the compute starts
-            # mid-sequence
-            self._assign_blocks(rt, slot)
-            self.cache["pos"] = self.cache["pos"].at[slot].set(
-                rt.cached_tokens)
-            rt.prefill_pos = rt.cached_tokens
-        ctx = self._context_tokens(rt)
-        n = len(ctx)
-        done = rt.prefill_pos
-        m = n - done if length is None else min(int(length), n - done)
-        if m <= 0:
-            raise ValueError(f"empty prefill span for request {rt.req_id}")
-        last = done + m >= n
-        whole = done == 0 and last
-        cache1 = None
-        if whole:
-            # whole-context fast path: the bucketed prefill jit.  SSM/
-            # hybrid states are sequence-order sensitive, so those archs
-            # prefill at exact length (one compile per distinct length).
-            L = n if self.cfg.ssm_layers else _bucket(n)
-            toks = np.zeros((1, L), np.int32)
-            toks[0, :n] = ctx
-            toks = jnp.asarray(toks)
-            # warm the jit cache per bucket so first-seen compile time
-            # never pollutes the engine clock / profiler samples
-            if ("prefill", L) not in self._warm:
+        with TraceAnnotation("engine.prefill", req=rt.req_id) as span:
+            if rt.phase is not Phase.PREFILLING:
+                raise ValueError(f"request {rt.req_id} has no staged prefill")
+            slot = rt.slot
+            if self.paged and not self._slot_blocks[slot]:
+                # first step: claim the pages (aliasing any prefix indexed
+                # since admission) and skip the cached span — its aliased
+                # pages are already populated, so the compute starts
+                # mid-sequence
+                self._assign_blocks(rt, slot)
+                self.cache["pos"] = self.cache["pos"].at[slot].set(
+                    rt.cached_tokens)
+                rt.prefill_pos = rt.cached_tokens
+            ctx = self._context_tokens(rt)
+            n = len(ctx)
+            done = rt.prefill_pos
+            m = n - done if length is None else min(int(length), n - done)
+            if m <= 0:
+                raise ValueError(
+                    f"empty prefill span for request {rt.req_id}")
+            span.set_metadata(tokens=m)
+            last = done + m >= n
+            whole = done == 0 and last
+            cache1 = None
+            if whole:
+                # whole-context fast path: the bucketed prefill jit.  SSM/
+                # hybrid states are sequence-order sensitive, so those archs
+                # prefill at exact length (one compile per distinct length).
+                L = n if self.cfg.ssm_layers else _bucket(n)
+                toks = np.zeros((1, L), np.int32)
+                toks[0, :n] = ctx
+                toks = jnp.asarray(toks)
+                # warm the jit cache per bucket so first-seen compile time
+                # never pollutes the engine clock / profiler samples
+                if ("prefill", L) not in self._warm:
+                    with self._warm_span("prefill", L):
+                        if self.paged:
+                            self._warm_paged(self._prefill_fn, toks, n,
+                                             slot)
+                        else:
+                            self._prefill_fn(self.params, toks,
+                                             n)[0].block_until_ready()
+                    self._warm.add(("prefill", L))
+                t0 = time.perf_counter()
                 if self.paged:
-                    self._warm_paged(self._prefill_fn, toks, n, slot)
+                    logits, self.cache = self._prefill_fn(
+                        self.params, self.cache, toks, n, slot)
                 else:
-                    self._prefill_fn(self.params, toks,
-                                     n)[0].block_until_ready()
-                self._warm.add(("prefill", L))
-            t0 = time.perf_counter()
-            if self.paged:
-                logits, self.cache = self._prefill_fn(
-                    self.params, self.cache, toks, n, slot)
+                    logits, cache1 = self._prefill_fn(self.params, toks, n)
+                row = logits[None, :]
+            elif self.paged:
+                # chunk/suffix continuation against the paged pool: padded
+                # to a pow-2 bucket with a traced valid length (padded rows
+                # route to the null page and are causally masked), so a
+                # ragged final chunk reuses the compiled bucket
+                L = _bucket(m)
+                toks = np.zeros((1, L), np.int32)
+                toks[0, :m] = ctx[done:done + m]
+                toks = jnp.asarray(toks)
+                if ("chunk", L) not in self._warm:
+                    with self._warm_span("chunk", L):
+                        self._warm_paged(self._chunk_fn, toks, slot, m)
+                    self._warm.add(("chunk", L))
+                t0 = time.perf_counter()
+                logits, self.cache = self._chunk_fn(self.params, self.cache,
+                                                    toks, slot, m)
+                row = logits[:, 0]
             else:
-                logits, cache1 = self._prefill_fn(self.params, toks, n)
-            row = logits[None, :]
-        elif self.paged:
-            # chunk/suffix continuation against the paged pool: padded
-            # to a pow-2 bucket with a traced valid length (padded rows
-            # route to the null page and are causally masked), so a
-            # ragged final chunk reuses the compiled bucket
-            L = _bucket(m)
-            toks = np.zeros((1, L), np.int32)
-            toks[0, :m] = ctx[done:done + m]
-            toks = jnp.asarray(toks)
-            if ("chunk", L) not in self._warm:
-                self._warm_paged(self._chunk_fn, toks, slot, m)
-                self._warm.add(("chunk", L))
-            t0 = time.perf_counter()
-            logits, self.cache = self._chunk_fn(self.params, self.cache,
-                                                toks, slot, m)
-            row = logits[:, 0]
-        else:
-            # dense chunk walk over a private single-slot cache (exact
-            # length: SSM recurrent state tolerates no pad tokens);
-            # committed to the pool only at completion
-            if slot not in self._partial:
-                self._partial[slot] = init_cache(self.cfg, 1,
-                                                 self.max_seq_len)
-            cache1 = self._partial[slot]
-            toks = jnp.asarray(np.asarray(ctx[done:done + m],
-                                          np.int32)[None])
-            if ("chunk", m) not in self._warm:
-                self._chunk_fn(self.params, cache1,
-                               toks)[0].block_until_ready()
-                self._warm.add(("chunk", m))
-            t0 = time.perf_counter()
-            logits, cache1 = self._chunk_fn(self.params, cache1, toks)
-            self._partial[slot] = cache1
-            row = logits[:, 0]
-        row.block_until_ready()
-        dt = time.perf_counter() - t0
-        self.clock += dt
-        if self.profiler is not None:
-            # chunk continuations are prefill work: feed them to the
-            # latency-model fit like whole-prompt prefills
-            self.profiler.observe_prefill(1, m, dt)
-        rt.prefill_pos = done + m
-        if not last:
-            return False
-        if not self.paged:
-            self._write_slot(slot, cache1 if whole
-                             else self._partial.pop(slot))
-        rt.phase = Phase.RUNNING
-        self._index_span(rt, n)
-        if rt.ttft_time is None:            # preserved across preemptions
-            rt.ttft_time = self.clock
-        self.key, sk = jax.random.split(self.key)
-        tok = int(sample(row, sk, self.temperature)[0])
-        self._push_token(rt, tok)
-        return True
+                # dense chunk walk over a private single-slot cache (exact
+                # length: SSM recurrent state tolerates no pad tokens);
+                # committed to the pool only at completion
+                if slot not in self._partial:
+                    self._partial[slot] = init_cache(self.cfg, 1,
+                                                     self.max_seq_len)
+                cache1 = self._partial[slot]
+                toks = jnp.asarray(np.asarray(ctx[done:done + m],
+                                              np.int32)[None])
+                if ("chunk", m) not in self._warm:
+                    with self._warm_span("chunk", m):
+                        self._chunk_fn(self.params, cache1,
+                                       toks)[0].block_until_ready()
+                    self._warm.add(("chunk", m))
+                t0 = time.perf_counter()
+                logits, cache1 = self._chunk_fn(self.params, cache1, toks)
+                self._partial[slot] = cache1
+                row = logits[:, 0]
+            with self.sync_span():
+                row.block_until_ready()
+            dt = time.perf_counter() - t0
+            self.clock += dt
+            if self.profiler is not None:
+                # chunk continuations are prefill work: feed them to the
+                # latency-model fit like whole-prompt prefills
+                self.profiler.observe_prefill(1, m, dt)
+            rt.prefill_pos = done + m
+            if not last:
+                return False
+            if not self.paged:
+                self._write_slot(slot, cache1 if whole
+                                 else self._partial.pop(slot))
+            rt.phase = Phase.RUNNING
+            self._index_span(rt, n)
+            if rt.ttft_time is None:            # preserved across preemptions
+                rt.ttft_time = self.clock
+            self.key, sk = jax.random.split(self.key)
+            with self.sync_span():
+                tok = int(sample(row, sk, self.temperature)[0])
+            self._push_token(rt, tok)
+            return True
 
     def prefill(self, rt: RuntimeRequest, slot: int):
         """Whole-prompt prefill: stage the slot and compute the full
@@ -787,12 +829,14 @@ class Engine:
         accum = int(np.max([rt.input_len + len(rt.generated)
                             for rt in running]))
         if "decode" not in self._warm:
-            if self.paged:
-                self._warm_paged(self._decode_fn, jnp.asarray(tokens),
-                                 jnp.asarray(active_np))
-            else:
-                self._decode_fn(self.params, self.cache, jnp.asarray(tokens),
-                                jnp.asarray(active_np))[0].block_until_ready()
+            with self._warm_span("decode", self.max_slots):
+                if self.paged:
+                    self._warm_paged(self._decode_fn, jnp.asarray(tokens),
+                                     jnp.asarray(active_np))
+                else:
+                    self._decode_fn(
+                        self.params, self.cache, jnp.asarray(tokens),
+                        jnp.asarray(active_np))[0].block_until_ready()
             self._warm.add("decode")
         t0 = time.perf_counter()
         logits, self.cache = self._decode_fn(

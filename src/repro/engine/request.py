@@ -30,6 +30,8 @@ class RuntimeRequest:
     slot: int = -1
     generated: List[int] = dataclasses.field(default_factory=list)
     submit_time: float = 0.0
+    # engine clock at the first begin_prefill (kept across preemptions)
+    admit_time: Optional[float] = None
     ttft_time: Optional[float] = None
     finish_time: Optional[float] = None
     preemptions: int = 0                 # times evicted (KV recomputed)

@@ -190,7 +190,9 @@ def main():
               f"ttft_mean={s['ttft_mean'] * 1e3:.1f}ms "
               f"tbt_p90={s['tbt_p90'] * 1e3:.2f}ms "
               f"tok/s={s['tokens_per_s']:.0f} "
-              f"preemptions={s['preemptions']}")
+              f"preemptions={s['preemptions']} "
+              f"queue_wait_p95={s['queue_wait_p95'] * 1e3:.1f}ms "
+              f"warms={s.get('warms', 0)}")
         return
     rts = _to_rts(pairs)
     respect = args.arrival_rate > 0

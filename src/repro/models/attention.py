@@ -98,15 +98,17 @@ def gqa_full(params, cfg, x, positions, *, cache_len: int = 0, ctx=None,
     """
     b, s, d = x.shape
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = jnp.dot(x, params["wq"]).reshape(b, s, h, hd)
-    k = jnp.dot(x, params["wk"]).reshape(b, s, kv, hd)
-    v = jnp.dot(x, params["wv"]).reshape(b, s, kv, hd)
-    if cfg.qk_norm:
-        q = rmsnorm(q, params["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, params["k_norm"], cfg.norm_eps)
-    cos, sin = rope_cos_sin(positions, hd, cfg.rope_theta, cfg.mrope_sections)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn_proj"):
+        q = jnp.dot(x, params["wq"]).reshape(b, s, h, hd)
+        k = jnp.dot(x, params["wk"]).reshape(b, s, kv, hd)
+        v = jnp.dot(x, params["wv"]).reshape(b, s, kv, hd)
+        if cfg.qk_norm:
+            q = rmsnorm(q, params["q_norm"], cfg.norm_eps)
+            k = rmsnorm(k, params["k_norm"], cfg.norm_eps)
+        cos, sin = rope_cos_sin(positions, hd, cfg.rope_theta,
+                                cfg.mrope_sections)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     seq_par = _seq_parallel_wanted(cfg, ctx, s)
     if seq_par:
         tp = ctx.tp_axis
@@ -116,27 +118,33 @@ def gqa_full(params, cfg, x, positions, *, cache_len: int = 0, ctx=None,
         v = _constrain(v, ctx, dpb, None, None, None)
     if not seq_par and _use_attn_kernel(cfg, s, attn_kernel):
         from repro.kernels import ops
-        out = ops.flash_attention(q, k, v, mode=attn_kernel, causal=True,
-                                  window=cfg.sliding_window)
-        out = out.reshape(b, s, h * hd)
+        with jax.named_scope("attend"):
+            out = ops.flash_attention(q, k, v, mode=attn_kernel,
+                                      causal=True, window=cfg.sliding_window)
+            out = out.reshape(b, s, h * hd)
+        with jax.named_scope("attn_proj"):
+            return jnp.dot(out, params["wo"]), (k, v)
+    with jax.named_scope("attend"):
+        rep = h // kv
+        qg = q.reshape(b, s, kv, rep, hd)
+        mask = _causal_mask(s, s, 0, cfg.sliding_window)
+        scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k) \
+            / jnp.sqrt(hd).astype(x.dtype)
+        if seq_par:
+            scores = _constrain(scores, ctx, dpb, None, None, ctx.tp_axis,
+                                None)
+        if cfg.attn_logit_softcap:
+            c = cfg.attn_logit_softcap
+            scores = c * jnp.tanh(scores / c)
+        scores = jnp.where(mask[None, None, None],
+                           scores.astype(jnp.float32), NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bgrqk,bkgd->bqgrd", probs,
+                         v).reshape(b, s, h * hd)
+        if seq_par:
+            out = _constrain(out, ctx, dpb, ctx.tp_axis, None)
+    with jax.named_scope("attn_proj"):
         return jnp.dot(out, params["wo"]), (k, v)
-    rep = h // kv
-    qg = q.reshape(b, s, kv, rep, hd)
-    mask = _causal_mask(s, s, 0, cfg.sliding_window)
-    scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k) \
-        / jnp.sqrt(hd).astype(x.dtype)
-    if seq_par:
-        scores = _constrain(scores, ctx, dpb, None, None, ctx.tp_axis, None)
-    if cfg.attn_logit_softcap:
-        c = cfg.attn_logit_softcap
-        scores = c * jnp.tanh(scores / c)
-    scores = jnp.where(mask[None, None, None], scores.astype(jnp.float32),
-                       NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v).reshape(b, s, h * hd)
-    if seq_par:
-        out = _constrain(out, ctx, dpb, ctx.tp_axis, None)
-    return jnp.dot(out, params["wo"]), (k, v)
 
 
 def _axes_prod(ctx) -> int:
@@ -435,60 +443,65 @@ def gqa_decode_paged(params, cfg, x, positions, layer_cache, block_tables,
     b, s, d = x.shape
     assert s == 1
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = jnp.dot(x, params["wq"]).reshape(b, 1, h, hd)
-    k = jnp.dot(x, params["wk"]).reshape(b, 1, kv, hd)
-    v = jnp.dot(x, params["wv"]).reshape(b, 1, kv, hd)
-    if cfg.qk_norm:
-        q = rmsnorm(q, params["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, params["k_norm"], cfg.norm_eps)
-    cos, sin = rope_cos_sin(positions, hd, cfg.rope_theta, cfg.mrope_sections)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn_proj"):
+        q = jnp.dot(x, params["wq"]).reshape(b, 1, h, hd)
+        k = jnp.dot(x, params["wk"]).reshape(b, 1, kv, hd)
+        v = jnp.dot(x, params["wv"]).reshape(b, 1, kv, hd)
+        if cfg.qk_norm:
+            q = rmsnorm(q, params["q_norm"], cfg.norm_eps)
+            k = rmsnorm(k, params["k_norm"], cfg.norm_eps)
+        cos, sin = rope_cos_sin(positions, hd, cfg.rope_theta,
+                                cfg.mrope_sections)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     quant = "k_scale" in layer_cache
-    new_cache = _paged_write_token(layer_cache, k, v, block_tables,
-                                   cache_pos, quant)
+    with jax.named_scope("kv_write"):
+        new_cache = _paged_write_token(layer_cache, k, v, block_tables,
+                                       cache_pos, quant)
     lengths = cache_pos + 1
-    if cfg.attn_logit_softcap:
-        # the paged kernel (like the dense one) has no logit softcap —
-        # gather the live pages and run the einsum path
-        P = new_cache["k"].shape[2]
-        L = block_tables.shape[1] * P
-        k_eff = gather_kv_pages(new_cache["k"], block_tables)
-        v_eff = gather_kv_pages(new_cache["v"], block_tables)
-        if quant:
-            k_eff = dequantize_kv(k_eff, gather_kv_pages(
-                new_cache["k_scale"], block_tables))
-            v_eff = dequantize_kv(v_eff, gather_kv_pages(
-                new_cache["v_scale"], block_tables))
-        rep = h // kv
-        qg = q.reshape(b, kv, rep, hd)
-        scores = jnp.einsum("bgrd,blgd->bgrl", qg,
-                            k_eff.astype(qg.dtype)) \
-            / jnp.sqrt(hd).astype(x.dtype)
-        c = cfg.attn_logit_softcap
-        scores = c * jnp.tanh(scores / c)
-        ln = lengths[:, None]
-        s_idx = jnp.arange(L, dtype=jnp.int32)[None, :]
-        t_s = ln - 1 - jnp.mod(ln - 1 - s_idx, L)
-        valid = t_s >= 0
-        if cfg.sliding_window > 0:
-            valid &= t_s > ln - 1 - cfg.sliding_window
-        scores = jnp.where(valid[:, None, None, :],
-                           scores.astype(jnp.float32), NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        o = jnp.einsum("bgrl,blgd->bgrd", probs,
-                       v_eff.astype(probs.dtype)).reshape(b, h, hd)
-    elif quant:
-        o = ops.decode_attention_paged_q8(
-            q[:, 0], new_cache["k"], new_cache["k_scale"], new_cache["v"],
-            new_cache["v_scale"], block_tables, lengths, mode=kernel_mode,
-            window=cfg.sliding_window)
-    else:
-        o = ops.decode_attention_paged(
-            q[:, 0], new_cache["k"], new_cache["v"], block_tables, lengths,
-            mode=kernel_mode, window=cfg.sliding_window)
-    out = o.astype(x.dtype).reshape(b, 1, h * hd)
-    return jnp.dot(out, params["wo"]), new_cache
+    with jax.named_scope("attend"):
+        if cfg.attn_logit_softcap:
+            # the paged kernel (like the dense one) has no logit softcap —
+            # gather the live pages and run the einsum path
+            P = new_cache["k"].shape[2]
+            L = block_tables.shape[1] * P
+            k_eff = gather_kv_pages(new_cache["k"], block_tables)
+            v_eff = gather_kv_pages(new_cache["v"], block_tables)
+            if quant:
+                k_eff = dequantize_kv(k_eff, gather_kv_pages(
+                    new_cache["k_scale"], block_tables))
+                v_eff = dequantize_kv(v_eff, gather_kv_pages(
+                    new_cache["v_scale"], block_tables))
+            rep = h // kv
+            qg = q.reshape(b, kv, rep, hd)
+            scores = jnp.einsum("bgrd,blgd->bgrl", qg,
+                                k_eff.astype(qg.dtype)) \
+                / jnp.sqrt(hd).astype(x.dtype)
+            c = cfg.attn_logit_softcap
+            scores = c * jnp.tanh(scores / c)
+            ln = lengths[:, None]
+            s_idx = jnp.arange(L, dtype=jnp.int32)[None, :]
+            t_s = ln - 1 - jnp.mod(ln - 1 - s_idx, L)
+            valid = t_s >= 0
+            if cfg.sliding_window > 0:
+                valid &= t_s > ln - 1 - cfg.sliding_window
+            scores = jnp.where(valid[:, None, None, :],
+                               scores.astype(jnp.float32), NEG_INF)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            o = jnp.einsum("bgrl,blgd->bgrd", probs,
+                           v_eff.astype(probs.dtype)).reshape(b, h, hd)
+        elif quant:
+            o = ops.decode_attention_paged_q8(
+                q[:, 0], new_cache["k"], new_cache["k_scale"], new_cache["v"],
+                new_cache["v_scale"], block_tables, lengths, mode=kernel_mode,
+                window=cfg.sliding_window)
+        else:
+            o = ops.decode_attention_paged(
+                q[:, 0], new_cache["k"], new_cache["v"], block_tables, lengths,
+                mode=kernel_mode, window=cfg.sliding_window)
+    with jax.named_scope("attn_proj"):
+        out = o.astype(x.dtype).reshape(b, 1, h * hd)
+        return jnp.dot(out, params["wo"]), new_cache
 
 
 def gqa_continue_paged(params, cfg, x, positions, layer_cache, block_tables,

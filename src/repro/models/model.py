@@ -112,16 +112,18 @@ def lm_head(params, cfg, x):
 
 
 def _attn_block_full(block, cfg, x, positions, ctx, attn_kernel=None):
+    with jax.named_scope("attn_proj"):
+        xin = apply_norm(x, block["attn_norm"], cfg)
     h, kv_out = (attn.mla_full if cfg.mla is not None else attn.gqa_full)(
-        block["attn"], cfg, apply_norm(x, block["attn_norm"], cfg), positions,
-        ctx=ctx, attn_kernel=attn_kernel)
+        block["attn"], cfg, xin, positions, ctx=ctx, attn_kernel=attn_kernel)
     x = x + h
-    y = apply_norm(x, block["mlp_norm"], cfg)
     aux = jnp.float32(0.0)
-    if "moe" in block:
-        y, aux = moe_ffn(block["moe"], cfg, y, ctx)
-    else:
-        y = apply_mlp(y, block["mlp"], cfg)
+    with jax.named_scope("mlp"):
+        y = apply_norm(x, block["mlp_norm"], cfg)
+        if "moe" in block:
+            y, aux = moe_ffn(block["moe"], cfg, y, ctx)
+        else:
+            y = apply_mlp(y, block["mlp"], cfg)
     return x + y, kv_out, aux
 
 
@@ -267,7 +269,9 @@ def forward_decode_paged(params, cfg: ModelConfig, *, tokens=None,
     (logits, new_cache).
     """
     assert cache is not None
-    x = embeds if embeds is not None else embed_tokens(params, cfg, tokens)
+    with jax.named_scope("embed"):
+        x = embeds if embeds is not None \
+            else embed_tokens(params, cfg, tokens)
     b = x.shape[0]
     cache_pos = cache["pos"]
     block_tables = cache["block_tables"]
@@ -287,7 +291,8 @@ def forward_decode_paged(params, cfg: ModelConfig, *, tokens=None,
             x = x + h
             new_layers.append({"conv": conv, "ssm": st})
             continue
-        xin = apply_norm(x, block["attn_norm"], cfg)
+        with jax.named_scope("attn_proj"):
+            xin = apply_norm(x, block["attn_norm"], cfg)
         if cfg.mla is not None:
             h, ckv, kpe = attn.mla_decode_paged(
                 block["attn"], cfg, xin, positions, layer_cache["ckv"],
@@ -298,15 +303,17 @@ def forward_decode_paged(params, cfg: ModelConfig, *, tokens=None,
                 block["attn"], cfg, xin, positions, layer_cache,
                 block_tables, cache_pos, kernel_mode=kernel_mode)
         x = x + h
-        y = apply_norm(x, block["mlp_norm"], cfg)
-        if "moe" in block:
-            y, _ = moe_ffn(block["moe"], cfg, y, ctx)
-        else:
-            y = apply_mlp(y, block["mlp"], cfg)
+        with jax.named_scope("mlp"):
+            y = apply_norm(x, block["mlp_norm"], cfg)
+            if "moe" in block:
+                y, _ = moe_ffn(block["moe"], cfg, y, ctx)
+            else:
+                y = apply_mlp(y, block["mlp"], cfg)
         x = x + y
         new_layers.append(new_lc)
-    x = apply_norm(x, params["final_norm"], cfg)
-    logits = lm_head(params, cfg, x)
+    with jax.named_scope("head"):
+        x = apply_norm(x, params["final_norm"], cfg)
+        logits = lm_head(params, cfg, x)
     return logits, {"pos": cache_pos + 1, "block_tables": block_tables,
                     "layers": new_layers}
 
@@ -325,7 +332,9 @@ def forward_prefill_paged(params, cfg: ModelConfig, *, tokens=None,
     (last-token logits [V], new_cache).
     """
     assert cache is not None
-    x = embeds if embeds is not None else embed_tokens(params, cfg, tokens)
+    with jax.named_scope("embed"):
+        x = embeds if embeds is not None \
+            else embed_tokens(params, cfg, tokens)
     b, s = x.shape[0], x.shape[1]
     if length is None:
         length = s
@@ -340,9 +349,11 @@ def forward_prefill_paged(params, cfg: ModelConfig, *, tokens=None,
             kv_out = state
         else:
             x, kv_out, _ = _attn_block_full(block, cfg, x, positions, ctx)
-        cache = write_prefill_paged(cache, i, kv_out, cfg, slot, length)
-    x = apply_norm(x, params["final_norm"], cfg)
-    logits = lm_head(params, cfg, x[:, length - 1][:, None])
+        with jax.named_scope("kv_write"):
+            cache = write_prefill_paged(cache, i, kv_out, cfg, slot, length)
+    with jax.named_scope("head"):
+        x = apply_norm(x, params["final_norm"], cfg)
+        logits = lm_head(params, cfg, x[:, length - 1][:, None])
     cache["pos"] = cache["pos"].at[slot].set(length)
     return logits[0, 0], cache
 

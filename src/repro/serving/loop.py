@@ -53,6 +53,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.latency_model import LinearLatencyModel
 from repro.core.policies import (ChunkedPrefill, make_discipline,
@@ -167,6 +168,7 @@ class ServeLoop:
         self._next_id = id_base
         self._stall_spins = 0
         self._stopped = False
+        self._admit_waits: List[float] = []  # first admissions this tick
 
     # ------------------------------------------------------------- clock
     def now(self) -> float:
@@ -205,10 +207,12 @@ class ServeLoop:
             if ("prefill", n) in eng._warm or n >= eng.max_seq_len:
                 continue
             toks = jnp.zeros((1, n), jnp.int32)
-            if eng.paged:
-                eng._warm_paged(eng._prefill_fn, toks, n, 0)
-            else:
-                eng._prefill_fn(eng.params, toks, n)[0].block_until_ready()
+            with eng._warm_span("prefill", n):
+                if eng.paged:
+                    eng._warm_paged(eng._prefill_fn, toks, n, 0)
+                else:
+                    eng._prefill_fn(eng.params, toks,
+                                    n)[0].block_until_ready()
             eng._warm.add(("prefill", n))
         # chunked disciplines: pre-warm the chunk buckets a plan can hit
         # (every pow-2 bucket up to the largest chunk, so ragged final
@@ -221,7 +225,8 @@ class ServeLoop:
             while L <= hi and L < eng.max_seq_len:
                 if ("chunk", L) not in eng._warm:
                     toks = jnp.zeros((1, L), jnp.int32)
-                    eng._warm_paged(eng._chunk_fn, toks, 0, L)
+                    with eng._warm_span("chunk", L):
+                        eng._warm_paged(eng._chunk_fn, toks, 0, L)
                     eng._warm.add(("chunk", L))
                 L *= 2
         self._t0 = time.perf_counter()
@@ -335,7 +340,9 @@ class ServeLoop:
             self._retune()
             return False
         view = eng.build_view(self._waiting, self.disc, self.model)
-        admit, preempt = normalize_decision(self.pol.decide(view), view)
+        with TraceAnnotation("sched.decide", waiting=len(self._waiting)):
+            decision = self.pol.decide(view)
+        admit, preempt = normalize_decision(decision, view)
         active_rts = eng.active_requests()
         did = False
         for j in preempt:
@@ -361,8 +368,11 @@ class ServeLoop:
         for j in sorted(sel, reverse=True):
             self._waiting.pop(j)
         for rt, slot in zip(chosen, free):
+            first = rt.admit_time is None
             # stage only: the prefill runs via this tick's plan below
             eng.begin_prefill(rt, slot)
+            if first:
+                self._admit_waits.append(rt.admit_time - rt.submit_time)
             did = True
         return did
 
@@ -419,16 +429,18 @@ class ServeLoop:
     def _consume(self, ticket: _Ticket):
         """Read back one round's sampled ids (syncing the device up to
         that round) and deliver them with wall timestamps."""
-        toks = np.asarray(ticket.tokens)
-        t = self.now()
-        for slot, rt, idx in ticket.parts:
-            # identity guard: deliver only if the request is still the
-            # running occupant of this slot and no token landed since
-            # dispatch (preempted/finished/reassigned -> drop overshoot)
-            if (rt.phase is not Phase.RUNNING or rt.slot != slot
-                    or len(rt.generated) != idx):
-                continue
-            self._deliver(rt, int(toks[slot]), t)
+        with TraceAnnotation("serve.consume", width=ticket.width):
+            with self.eng.sync_span("serve.sync"):
+                toks = np.asarray(ticket.tokens)
+            t = self.now()
+            for slot, rt, idx in ticket.parts:
+                # identity guard: deliver only if the request is still the
+                # running occupant of this slot and no token landed since
+                # dispatch (preempted/finished/reassigned -> drop overshoot)
+                if (rt.phase is not Phase.RUNNING or rt.slot != slot
+                        or len(rt.generated) != idx):
+                    continue
+                self._deliver(rt, int(toks[slot]), t)
 
     def _deliver(self, rt: RuntimeRequest, tok: int, t: float):
         eng = self.eng
@@ -451,7 +463,8 @@ class ServeLoop:
             first_token=evs[0].t if evs else None,
             finish=evs[-1].t if evs else None,
             n_tokens=len(evs), tbt=stream.tbts(),
-            preemptions=rt.preemptions, cached_tokens=rt.cached_tokens))
+            preemptions=rt.preemptions, cached_tokens=rt.cached_tokens,
+            admit=rt.admit_time))
 
     # -------------------------------------------------------------- tick
     def _idle(self) -> bool:
@@ -489,10 +502,18 @@ class ServeLoop:
         (overlap) or round N (sync) -> gauges.  Under a chunked
         discipline a long prompt's chunk and the running decode round
         share every tick (chunk-as-tick)."""
+        with TraceAnnotation("serve.tick"):
+            self._tick()
+
+    def _tick(self):
+        eng = self.eng
+        t0, warms, sync = time.perf_counter(), eng.warm_runs, eng.sync_s
         t = self.now()
-        self._ingest(t)
+        with TraceAnnotation("serve.ingest"):
+            self._ingest(t)
         self.eng.clock = t          # engine stamps land on the wall clock
-        admitted = self._schedule()
+        with TraceAnnotation("serve.schedule"):
+            admitted = self._schedule()
         pre_tok = self._run_prefill_plan()
         ticket = self._dispatch_round()
         prev, self._inflight = self._inflight, ticket
@@ -501,6 +522,7 @@ class ServeLoop:
         if not self.overlap and ticket is not None:
             self._consume(ticket)
             self._inflight = None
+        waits, self._admit_waits = tuple(self._admit_waits), []
         self.metrics.on_gauge(StepGauge(
             t=t, queue_depth=len(self._waiting),
             active=sum(not f for f in self.eng.slot_free),
@@ -510,7 +532,10 @@ class ServeLoop:
             prefill_tokens=pre_tok,
             prefilling=sum(1 for rt in self.eng.slot_req
                            if rt is not None
-                           and rt.phase is Phase.PREFILLING)))
+                           and rt.phase is Phase.PREFILLING),
+            warms=eng.warm_runs - warms,
+            tick_s=time.perf_counter() - t0, sync_s=eng.sync_s - sync,
+            queue_waits=waits))
         # stall detection: completely idle with a non-empty queue and a
         # policy that admits nothing (matches the batch loop's guard)
         if (ticket is None and self._inflight is None and self._waiting

@@ -9,7 +9,7 @@ modelled/engine-clock results after a batch run.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -30,6 +30,14 @@ class RequestTimeline:
     preemptions: int = 0
     cached_tokens: int = 0
     rejected: bool = False
+    admit: Optional[float] = None  # first admission (prefill staged)
+
+    @property
+    def queue_wait(self) -> Optional[float]:
+        """Waiting queue to first admission; kept across preemptions."""
+        if self.admit is None:
+            return None
+        return self.admit - self.submit
 
     @property
     def ttft(self) -> Optional[float]:
@@ -63,6 +71,10 @@ class StepGauge:
     overlapped: bool            # a step was in flight during this tick
     prefill_tokens: int = 0     # prompt tokens computed this tick (plan)
     prefilling: int = 0         # slots mid-prefill after this tick
+    warms: int = 0              # engine warm-path entries (compiles)
+    tick_s: float = 0.0         # host wall seconds of the tick
+    sync_s: float = 0.0         # of which blocked on the device
+    queue_waits: Tuple[float, ...] = ()   # of requests first admitted
 
     @property
     def mixed(self) -> bool:
@@ -126,6 +138,7 @@ class ServingMetrics:
                 and tl.finish is not None]
         rejected = sum(tl.rejected for tl in self.timelines.values())
         ttfts = [tl.ttft for tl in done if tl.ttft is not None]
+        waits = [tl.queue_wait for tl in done if tl.queue_wait is not None]
         tbts = [g for tl in done for g in tl.tbt]
         e2es = [tl.e2e for tl in done]
         n_tokens = sum(tl.n_tokens for tl in done)
@@ -141,6 +154,7 @@ class ServingMetrics:
             "tokens_per_s": n_tokens / wall if wall > 0 else 0.0,
             "ttft_mean": float(np.mean(ttfts)) if ttfts else 0.0,
             "ttft_p90": _pct(ttfts, 90),
+            "queue_wait_p95": _pct(waits, 95),
             "tbt_mean": float(np.mean(tbts)) if tbts else 0.0,
             "tbt_p50": _pct(tbts, 50),
             "tbt_p90": _pct(tbts, 90),
@@ -162,6 +176,8 @@ class ServingMetrics:
             # shares its tick with an in-flight decode's delivery
             out["mixed_tick_frac"] = float(
                 np.mean([g.mixed for g in self.gauges]))
+            # compiles or warm runs paid while serving (ideally 0)
+            out["warms"] = sum(g.warms for g in self.gauges)
         return out
 
     def rows(self, prefix: str = "serve"):
