@@ -36,6 +36,8 @@ from .weights import make_params
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+# the step programs' names in the device trace's ``XLA Modules`` line
+DECODE_PROGRAM, PREFILL_PROGRAM = "decode_dispatch_paged", "prefill_paged"
 
 
 # ------------------------------------------------------------- the cell
@@ -142,7 +144,7 @@ class Record:
     decode_ctx: List[List[int]] = dataclasses.field(default_factory=list)
     prefill_n: List[int] = dataclasses.field(default_factory=list)
     trace: object = None                 # trace_reduce.Trace
-    window_ns: tuple = (0, 0)
+    window_ns: tuple = (0, 0)            # the part the trace holds
     device: Optional[str] = None         # device plane read
     on: bool = False                     # inside the traced window
 
@@ -489,23 +491,78 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     return out
 
 
+def _step_starts(spans, name: str, lo: int, hi: int, n: int) -> List[int]:
+    """The start of each ``name`` span in ``[lo, hi)``, in order: one per
+    step record the harness kept in the window (``n``), or an error."""
+    starts = sorted(s.start for s in spans
+                    if s.name == name and lo <= s.start < hi)
+    if len(starts) != n:
+        raise RuntimeError(
+            f"the trace holds {len(starts)} {name} spans in the window "
+            f"against {n} steps recorded: the host trace is not whole")
+    return starts
+
+
+def _runs(mods, program: str, lo: int, hi: int) -> int:
+    """Runs of a step program that start in ``[lo, hi)``."""
+    return sum(1 for e in mods if program in e.name and lo <= e.start < hi)
+
+
+def _traced_part(rec: Record, lo: int, hi: int) -> tuple:
+    """The part of the window ``[lo, hi)`` that the trace holds, and the
+    decode and prefill program runs in it.  Where the device plane holds
+    fewer runs of a step program than the harness dispatched, or reports
+    dropped trace buffers, it lost its events past some point: the window
+    ends where ``trace_reduce.traced`` says, and ``rec`` keeps the steps
+    whose host span starts before that, no more of them than the part
+    holds runs (the last steps dispatched may have lost theirs).  A whole
+    trace keeps ``[lo, hi)``."""
+    from . import trace_reduce
+    ops = rec.trace.ops.get(rec.device, [])
+    mods = rec.trace.modules.get(rec.device, [])
+    decode_t = _step_starts(rec.trace.spans, "bench.dispatch", lo, hi,
+                            len(rec.decode_ctx))
+    prefill_t = _step_starts(rec.trace.spans, "bench.prefill", lo, hi,
+                             len(rec.prefill_n))
+    if rec.device is not None and (
+            _runs(mods, DECODE_PROGRAM, lo, hi) < len(decode_t)
+            or _runs(mods, PREFILL_PROGRAM, lo, hi) < len(prefill_t)
+            or rec.trace.dropped.get(rec.device)):
+        lo, hi = trace_reduce.traced(ops + mods, lo, hi)
+        if hi <= lo:
+            raise RuntimeError("the trace holds no device event in the window")
+        rec.decode_ctx = [r for r, t in zip(rec.decode_ctx, decode_t)
+                          if t < hi][:_runs(mods, DECODE_PROGRAM, lo, hi)]
+        rec.prefill_n = [r for r, t in zip(rec.prefill_n, prefill_t)
+                         if t < hi][:_runs(mods, PREFILL_PROGRAM, lo, hi)]
+    rec.window_ns = (lo, hi)
+    return (_runs(mods, DECODE_PROGRAM, lo, hi),
+            _runs(mods, PREFILL_PROGRAM, lo, hi))
+
+
 def _per_layer(cell: Cell, rec: Record, prof_dir: str, device: dict,
                out: dict, log) -> dict:
-    """Reduce the window's trace: device busy time, the breakdown, and
-    every per-layer reader's number."""
+    """Reduce the window's trace over the part of it that the trace holds
+    (``_traced_part``): device busy time, the breakdown, and every
+    per-layer reader's number.  ``device`` gains ``busy_s`` and
+    ``window_s`` over that part and ``traced_share``, that part over the
+    whole window; host counters (gauges, decide times) keep the whole
+    window."""
     from . import trace_reduce
     files = glob.glob(os.path.join(prof_dir, "**", "*.xplane.pb"),
                       recursive=True)
     rec.trace = trace_reduce.load(files[0])
     shutil.rmtree(prof_dir, ignore_errors=True)
     win = next(s for s in rec.trace.spans if s.name == "bench.window")
-    rec.window_ns = (win.start, win.end)
     rec.device = (rec.trace.devices or [None])[0]
+    dispatched = (len(rec.decode_ctx), len(rec.prefill_n))
+    decode_runs, prefill_runs = _traced_part(rec, win.start, win.end)
     lo, hi = rec.window_ns
     ops = rec.trace.ops.get(rec.device, [])
     busy = trace_reduce.busy(ops, lo, hi)
     device["busy_s"] = trace_reduce.total(busy) / 1e9
     device["window_s"] = (hi - lo) / 1e9
+    device["traced_share"] = (hi - lo) / win.dur
     top = sorted(trace_reduce.time_by_name(ops, lo, hi).items(),
                  key=lambda kv: -kv[1])[:10]
     gaps = trace_reduce.labelled_gaps(trace_reduce.gaps(busy, lo, hi),
@@ -513,7 +570,10 @@ def _per_layer(cell: Cell, rec: Record, prof_dir: str, device: dict,
     out["breakdown"] = {"device_ops": [[n, ns / 1e9] for n, ns in top],
                         "idle_gaps": [[n, ns / 1e9] for n, ns in gaps]}
     log("per-layer counts: " + json.dumps({
-        "decode_steps": len(rec.decode_ctx), "prefills": len(rec.prefill_n),
+        "decode_steps": len(rec.decode_ctx), "decode_modules": decode_runs,
+        "prefills": len(rec.prefill_n), "prefill_modules": prefill_runs,
+        "decode_dispatched": dispatched[0],
+        "prefills_dispatched": dispatched[1],
         "decides": len(rec.decide_s), "ticks": len(rec.gauges)}))
     readers = _load_readers(cell)
     metrics = {}

@@ -10,7 +10,13 @@ asks for.  ``--trace 0`` reports the cell's end-to-end metrics, ``--trace
 standard output is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, with ``--trace 1`` a ``breakdown``,
 and last ``checks``: each number compared beside its limit); the last
-lines of standard error repeat the compared numbers.  Without a TPU, or
+lines of standard error repeat the compared numbers.  With ``--trace 1``
+every device-trace number is read over the part of the window that the
+profiler kept: ``device`` gives ``busy_s`` and ``window_s`` over that
+part and ``traced_share``, that part over the whole window, and the
+``per-layer counts:`` line the steps counted beside the step programs'
+runs in the trace (``decode_steps`` / ``decode_modules``, ``prefills`` /
+``prefill_modules``) and those the host dispatched.  Without a TPU, or
 with fewer chips than the cell asks for, it exits non-zero and prints no
 result.
 """

@@ -42,3 +42,56 @@ def test_op_names_fold_to_the_instruction():
     assert tr.op_name("%copy-start.10") == "copy-start"
     assert tr.module_name("jit__decode_dispatch_paged(1408)") \
         == "jit__decode_dispatch_paged"
+
+
+# a decode step every 10 ns from 0 to 100: the host's span starts 1 ns
+# before the device's 6 ns program run
+STEPS = [Event("jit__decode_dispatch_paged(7)", t, t + 6)
+         for t in range(0, 100, 10)]
+SPANS = [Event("bench.dispatch", t - 1, t) for t in range(0, 100, 10)]
+
+
+def test_the_traced_part_ends_at_the_last_device_event():
+    assert tr.traced(STEPS[:4], -5, 120) == (-5, 36)
+    # an event that starts before the window, or runs past its end
+    assert tr.traced(STEPS, 15, 95) == (15, 95)
+    assert tr.traced(STEPS[:1], 15, 95) == (15, 15)
+
+
+def _part(modules, spans=SPANS, steps=10, dropped=0):
+    from onchip import harness
+    rec = harness.Record(spec=None, peak={}, device="/device:TPU:0",
+                         decode_ctx=[[i] for i in range(steps)])
+    rec.trace = tr.Trace(ops={rec.device: modules},
+                         modules={rec.device: modules}, spans=spans,
+                         dropped={rec.device: dropped})
+    runs = harness._traced_part(rec, -5, 120)
+    return rec.window_ns, runs, [c[0] for c in rec.decode_ctx]
+
+
+def test_a_whole_trace_keeps_its_window():
+    assert _part(STEPS) == ((-5, 120), (10, 0), list(range(10)))
+
+
+def test_a_dropped_tail_ends_the_window_and_the_steps_counted():
+    # the device kept four runs; the host dispatched ten
+    assert _part(STEPS[:4]) == ((-5, 36), (4, 0), [0, 1, 2, 3])
+    # the fifth step's span started before the fourth run ended, but its
+    # own run was lost: it is not counted
+    late = SPANS[:4] + [Event("bench.dispatch", 33, 34)] + SPANS[5:]
+    assert _part(STEPS[:4], late) == ((-5, 36), (4, 0), [0, 1, 2, 3])
+    # a plane that reports dropped buffers ends at its last event too
+    assert _part(STEPS, dropped=3)[0] == (-5, 96)
+
+
+def test_step_records_pair_with_their_spans_or_the_run_fails():
+    import pytest
+    from onchip import harness
+    spans = [Event("bench.dispatch", t, t + 2) for t in (30, 10, 50)] \
+        + [Event("bench.prefill", 12, 14), Event("bench.dispatch", 200, 201)]
+    assert harness._step_starts(spans, "bench.dispatch", 0, 100, 3) \
+        == [10, 30, 50]
+    with pytest.raises(RuntimeError, match="not whole"):
+        harness._step_starts(spans, "bench.dispatch", 0, 100, 4)
+    with pytest.raises(RuntimeError, match="not whole"):
+        _part(STEPS, steps=11)
