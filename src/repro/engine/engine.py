@@ -166,10 +166,11 @@ class Engine:
             self.cache = init_paged_cache(cfg, max_slots, max_seq_len,
                                           num_blocks, block_size)
             jit_kw = self._init_mesh(mesh, parallelism, kernel_mode)
-            # the paged step fns donate the cache: page writes are
-            # in-place scatters, never O(pool) copies (out_shardings
-            # matching the committed input keeps donation exact under
-            # a mesh)
+            # the paged step fns donate the cache, so page writes
+            # scatter into the pool's own buffer (out_shardings matching
+            # the committed input keeps donation exact under a mesh);
+            # donation alone does not keep a scatter free of layout
+            # copies of the pool (models/cache.kv_token_write)
             self._decode_fn = jax.jit(self._decode_step_paged,
                                       donate_argnums=(1,), **jit_kw)
             self._prefill_fn = jax.jit(self._prefill_paged,

@@ -191,16 +191,30 @@ def paged_token_write(pages, vals, page_ids, offs):
     """Scatter one token per sequence into a latent pool.
 
     pages: [N, P, ...]; vals: [B, ...]; page_ids/offs: [B] int32.
-    O(B) — independent of pool size (and in-place under jit donation)."""
+    O(B) — independent of pool size (and in place under jit donation:
+    the indexed dims are the major ones, so XLA scatters into the
+    donated buffer with no layout copy)."""
     return pages.at[page_ids, offs].set(vals.astype(pages.dtype))
 
 
 def kv_token_write(pages, vals, page_ids, offs):
-    """:func:`paged_token_write` for a kv-head-major K/V pool.
+    """Write rows into a kv-head-major K/V pool, in place: one new token
+    per sequence, or a prefilled span (:func:`kv_prefill_write`).
 
-    pages: [KV, N, P, d]; vals: [B, KV, d]; page_ids/offs: [B] int32."""
-    return pages.at[:, page_ids, offs].set(
-        jnp.swapaxes(vals, 0, 1).astype(pages.dtype))
+    pages: [KV, N, P, d]; vals: [R, KV, d]; page_ids/offs: [R] int32.
+    Each head's ``[N, P, d]`` pages take their rows as a latent pool does
+    (:func:`paged_token_write`), vmapped with the indices batched too: one
+    scatter with (head, page, offset) as its index dims, all major, and
+    ``d`` as its window.  With the head dim left a window
+    (``pages.at[:, page_ids, offs]``) TPU copies the whole pool into
+    another layout and back around the scatter; folding the head into a
+    flat row index instead would all-gather the rows of a head-sharded
+    pool under a mesh.  O(R) under jit donation."""
+    kv = pages.shape[0]
+    page_ids = jnp.broadcast_to(page_ids, (kv,) + page_ids.shape)
+    offs = jnp.broadcast_to(offs, (kv,) + offs.shape)
+    return jax.vmap(paged_token_write)(pages, jnp.swapaxes(vals, 0, 1),
+                                       page_ids, offs)
 
 
 def _prefill_targets(block_table, S, P, n, start):
@@ -235,8 +249,7 @@ def kv_prefill_write(pages, vals, block_table, n, start=0):
     pages: [KV, N, P, d]; vals: [S, KV, d]."""
     page_ids, offs = _prefill_targets(block_table, vals.shape[0],
                                       pages.shape[2], n, start)
-    return pages.at[:, page_ids, offs].set(
-        jnp.swapaxes(vals, 0, 1).astype(pages.dtype))
+    return kv_token_write(pages, vals, page_ids, offs)
 
 
 def write_prefill_paged(cache, layer_idx: int, kv_tuple, cfg: ModelConfig,

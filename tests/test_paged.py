@@ -111,6 +111,62 @@ def test_paged_kernel_q8_matches_ref():
     np.testing.assert_allclose(out, want, atol=5e-3, rtol=5e-3)
 
 
+# --------------------------------------------------------- cache writers
+# (kind, int8 pool with scales, rows S, valid n, span start): token rows
+# carry their own (page, offset); a span's come from _prefill_targets
+# over a 4-page ring of 16 rows
+WRITES = {
+    "token-bf16": ("token", False, 5, 5, 0),
+    "token-int8": ("token", True, 5, 5, 0),
+    "token-dead-slots": ("token", False, 6, 6, 0),
+    "span-bf16": ("span", False, 12, 12, 0),
+    "span-int8-padded": ("span", True, 12, 7, 0),
+    "span-padded": ("span", False, 12, 5, 3),
+    "span-wraps-ring": ("span", False, 12, 12, 10),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WRITES))
+def test_kv_writes_match_head_window_scatter(case):
+    """The K/V writers store what ``pages.at[:, page_ids, offs].set``
+    stores, bit for bit, on every page but the null page (where dead
+    slots and padded rows collide, and which no live read uses)."""
+    from repro.models.cache import (NULL_PAGE, _prefill_targets,
+                                    kv_prefill_write, kv_token_write)
+    kind, quantized, S, n, start = WRITES[case]
+    KV, N, P, d = 2, 9, 4, 8
+    ks = jax.random.split(jax.random.PRNGKey(S + n + start), 3)
+    vals = jax.random.normal(ks[0], (S, KV, d), jnp.float32)
+    if quantized:
+        q, s = quantize_kv(vals)
+        writes = [(jax.random.randint(ks[1], (KV, N, P, d), -127, 128,
+                                      jnp.int8), q),
+                  (jax.random.normal(ks[2], (KV, N, P, 1), jnp.bfloat16),
+                   s)]
+    else:
+        writes = [(jax.random.normal(ks[1], (KV, N, P, d), jnp.bfloat16),
+                   vals)]
+    bt = jnp.asarray([3, 7, 1, 5], jnp.int32)
+    if kind == "token":
+        page_ids = jnp.asarray([4, 8, 2, 6, 3, 2][:S], jnp.int32)
+        offs = jnp.asarray([0, 3, 1, 2, 3, 0][:S], jnp.int32)
+        if case == "token-dead-slots":
+            page_ids = page_ids.at[1:4].set(NULL_PAGE)
+            offs = offs.at[1:4].set(2)
+        write = jax.jit(lambda p, x: kv_token_write(p, x, page_ids, offs))
+    else:
+        page_ids, offs = _prefill_targets(bt, S, P, n, start)
+        write = jax.jit(lambda p, x: kv_prefill_write(p, x, bt, n, start))
+    live = np.arange(N) != NULL_PAGE
+    for pages, x in writes:
+        want = pages.at[:, page_ids, offs].set(
+            jnp.swapaxes(x, 0, 1).astype(pages.dtype))
+        got = write(pages, x)
+        assert got.dtype == pages.dtype and got.shape == pages.shape
+        np.testing.assert_array_equal(np.asarray(got)[:, live],
+                                      np.asarray(want)[:, live])
+
+
 # ------------------------------------------------------------- model level
 def _identity_tables(B, npg):
     return jnp.arange(1, 1 + B * npg, dtype=jnp.int32).reshape(B, npg)

@@ -305,6 +305,24 @@ def test_sharded_serving_multidevice():
     assert "ALL OK" in out.stdout
 
 
+def test_kv_writes_stay_local_on_head_sharded_pool():
+    """Token and prefill-span K/V writes on a pool head-sharded as the
+    engine's mesh places it (forced 4-device CPU host, subprocess): no
+    collective, the pool's sharding kept, the head-window scatter's
+    values off the null page."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable,
+         os.path.join(root, "tests", "helpers", "verify_kv_write_mesh.py")],
+        env=dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+                 JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "ALL OK" in out.stdout
+
+
 def test_moe_shard_map_matches_local():
     """MoE FFN with a mesh ctx == MoE FFN without (1x1 mesh)."""
     from repro.models.moe import ShardingCtx, init_moe, moe_ffn

@@ -4,6 +4,7 @@ a compile that passes shows only that the chip's compiler accepts the
 kernel (block tiling, VMEM scratch layout), which interpret-mode tests
 cannot show.  The topology is described inside a fixture so that no
 module import loads the TPU compiler library."""
+import math
 import os
 import re
 
@@ -14,6 +15,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.decode_attention_paged import (decode_attention_paged,
                                                   decode_attention_paged_q8)
+from repro.models.cache import kv_prefill_write, kv_token_write
 
 # (q heads, kv heads, head_dim) of the served GQA configurations
 WIDTHS = {
@@ -21,6 +23,8 @@ WIDTHS = {
     "qwen2.5-7b": (28, 4, 128),       # the paper's model: group of 7
 }
 SLOTS, PAGES_PER_SLOT = 8, 16
+# qwen3-1.7b's served K/V pool: [kv heads, 256 pages, 256 rows, head_dim]
+POOL = (8, 256, 256, 128)
 
 
 @pytest.fixture(scope="module")
@@ -87,3 +91,29 @@ def test_windowed_paged_decode_compiles_for_v5e(one_chip):
     compiled = fn.lower(*_args(one_chip, "qwen3-1.7b", 16, False),
                         window=100).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("write,rows", [(kv_token_write, 8),
+                                        (kv_prefill_write, 1024)],
+                         ids=["token", "prefill"])
+def test_kv_write_compiles_in_place_for_v5e(one_chip, write, rows):
+    """A donated K/V write scatters into the pool's own buffer: the
+    optimized program holds no copy or transpose as large as the pool
+    (a write that keeps the head dim as a scatter window makes two, the
+    pool into the scatter's layout and back)."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pages = sds(POOL, jnp.bfloat16)
+    vals = sds((rows,) + POOL[:1] + POOL[3:], jnp.bfloat16)
+    if write is kv_token_write:
+        rest = (sds((rows,), jnp.int32), sds((rows,), jnp.int32))
+    else:                       # one slot's table, valid rows, span start
+        rest = (sds((PAGES_PER_SLOT,), jnp.int32), sds((), jnp.int32),
+                sds((), jnp.int32))
+    hlo = jax.jit(write, donate_argnums=(0,)).lower(
+        pages, vals, *rest).compile().as_text()
+    pool_sized = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", hlo)
+        if math.prod(int(x) for x in m.group(1).split(",")) ==
+        math.prod(POOL)]
+    assert pool_sized == []
