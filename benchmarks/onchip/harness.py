@@ -24,15 +24,14 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import reference, traffic, work
-from .model import Spec, load_spec, program_config
-from .weights import make_params
+from . import model, reference, traffic, work
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -45,7 +44,8 @@ DECODE_PROGRAM, PREFILL_PROGRAM = "decode_dispatch_paged", "prefill_paged"
 class Cell:
     name: str
     chips: int
-    spec: Spec
+    arch: ModuleType                    # archs/<arch>.py, as the config names
+    spec: object                        # the arch module's spec
     mix: traffic.Mix
     check: dict                         # limits of the comparison
     end_to_end: List[dict]
@@ -59,7 +59,8 @@ class Cell:
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
     """The cell ``name`` of ``root/BENCHMARK.json`` and its files, found
-    by the names the entry gives."""
+    by the names the entry gives: the configuration and the architecture
+    module it names, and the traffic mix."""
     with open(root / "BENCHMARK.json") as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -68,15 +69,15 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                          f"known: {sorted(cells)}")
     w = cells[name]
     d = root / "benchmarks" / "onchip"
-    with open(d / "configs" / f"{w['config']}.json") as f:
-        check = json.load(f).get("check", {})
+    config, arch = model.load_config(d / "configs" / f"{w['config']}.json",
+                                     d / "archs")
 
     def mine(m):
         return name in m.get("workloads", [name])
-    return Cell(name=name, chips=int(w["chips"]),
-                spec=load_spec(d / "configs" / f"{w['config']}.json"),
+    return Cell(name=name, chips=int(w["chips"]), arch=arch,
+                spec=arch.load_spec(config),
                 mix=traffic.load_mix(d / "traffic" / f"{w['traffic']}.json"),
-                check=check,
+                check=config.get("check", {}),
                 end_to_end=[m for m in bench["end_to_end"] if mine(m)],
                 per_layer=[m for m in bench["per_layer"] if mine(m)],
                 root=root)
@@ -137,7 +138,8 @@ class CompileClock:
 @dataclasses.dataclass
 class Record:
     """What the traced run gathers for the per-layer readers."""
-    spec: Spec
+    arch: ModuleType                     # the cell's, for its work counts
+    spec: object
     peak: dict
     gauges: list = dataclasses.field(default_factory=list)
     decide_s: List[float] = dataclasses.field(default_factory=list)
@@ -277,7 +279,7 @@ def setup(cell: Cell, seed: int, max_prompt: int) -> Server:
     from repro.engine.engine import Engine
     from repro.launch.serve import fit_latency_model
     from repro.serving import ServeLoop
-    spec, mix = cell.spec, cell.mix
+    arch, spec, mix = cell.arch, cell.spec, cell.mix
     clock, split = CompileClock(), {}
     t = time.perf_counter()
 
@@ -286,9 +288,9 @@ def setup(cell: Cell, seed: int, max_prompt: int) -> Server:
         split[name] = time.perf_counter() - t
         t = time.perf_counter()
 
-    params = make_params(spec, seed)
+    params = arch.make_params(spec, seed)
     lap("init")
-    cfg = program_config(spec)
+    cfg = arch.program_config(spec)
     rng = np.random.default_rng(np.random.SeedSequence([int(seed), 3]))
     lat_model = fit_latency_model(cfg, params, spec.max_slots, rng)
     lap("fit")
@@ -403,7 +405,7 @@ def compare(cell: Cell, params, done: Dict[int, tuple], seed: int,
     pick = reference.pick_sample(done, seed,
                                  cell.check.get("min_tokens", 512),
                                  cell.check.get("max_requests", 8))
-    ref = reference.Reference(cell.spec, params, control=control)
+    ref = cell.arch.Reference(cell.spec, params, control=control)
     widest, widest_ctl, n_tok = 0.0, 0.0, 0
     for i in pick:
         g, c = ref.gaps(*done[i])
@@ -427,7 +429,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     reqs = traffic.generate(mix, seed, seconds, spec.vocab,
                             max_total=spec.max_seq_len)
     srv = setup(cell, seed, max(r.input_len for r in reqs))
-    rec = Record(spec=spec, peak=peak)
+    rec = Record(arch=cell.arch, spec=spec, peak=peak)
     if trace:
         _instrument(srv.loop, srv.eng, rec)
     prof_dir = tempfile.mkdtemp(prefix="onchip_trace_") if trace else None

@@ -1,8 +1,9 @@
 """mfu.decode (%): roofline share of the whole decode step.  The least
 time of every decode step dispatched in the traced window (the larger of
 its FLOPs over peak and its bytes over peak HBM bandwidth: weights once,
-live K/V read, new K/V written) over the device time of the decode
-program (``_decode_dispatch_paged``) in the trace."""
+live K/V read, new K/V written), as the cell's architecture module
+counts it (``decode_step``), over the device time of the decode program
+(``_decode_dispatch_paged``) in the trace."""
 from onchip import trace_reduce, work
 
 
@@ -15,6 +16,7 @@ def read(rec):
         if "decode_dispatch_paged" in name)
     if t <= 0:
         return None
-    least = sum(work.roofline_s(*work.decode_step(rec.spec, ctx), rec.peak)
+    least = sum(work.roofline_s(*rec.arch.decode_step(rec.spec, ctx),
+                              rec.peak)
                 for ctx in rec.decode_ctx if ctx)
     return 100.0 * least / (t / 1e9)

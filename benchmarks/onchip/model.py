@@ -1,90 +1,84 @@
-"""A configuration file read into the shapes the benchmark works with.
+"""A configuration file and the architecture module it names.
 
 ``configs/<name>.json`` holds the published config.json keys as they are
-run, the ``program`` block (what the keys do not say: norm, MLP, qk-norm,
-dtype) and the ``serving`` block (slots, pages, pool).  :class:`Spec` is
-the one view of it that the weights, the reference, the work functions
-and the program's ``ModelConfig`` are all built from.
+run, the ``program`` block (what the keys do not say: family, norm, MLP,
+dtype), the ``serving`` block (slots, pages, pool), the ``check`` block
+(the comparison's limits) and ``"arch"``: the name of the file
+``archs/<arch>.py`` that knows the architecture.  There is no default:
+a configuration without ``"arch"``, or one naming no such file, is
+refused.  Each architecture module gives:
+
+* ``load_spec(config: dict) -> spec``: the one view of the configuration
+  that the rest are built from.  The harness itself reads only the
+  spec's ``name``, ``vocab``, ``layers``, ``max_slots``, ``block_size``,
+  ``max_seq_len`` and ``num_blocks``;
+* ``program_config(spec)``: the system under test's ``ModelConfig``;
+* ``make_params(spec, seed)``: the weights tree in the program's layout,
+  made on the device from ``seed`` (``weights.on_device``);
+* ``Reference(spec, params, control=False)``, whose ``gaps(prompt,
+  served)`` gives ``(gaps, control_gaps)`` (``reference.py``);
+* ``decode_step(spec, ctx)`` and ``prefill(spec, n)``: (FLOPs, bytes),
+  the least work of a step (the rule is in ``work.py``).
+
+A module may give more for the per-layer readers of its own cells (the
+dense module's ``paged_attention``).
 """
 from __future__ import annotations
 
-import dataclasses
+import importlib.util
 import json
+import re
+import sys
 from pathlib import Path
+from types import ModuleType
+from typing import Dict, Tuple
+
+ARCHS = Path(__file__).resolve().parent / "archs"
+_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
+_LOADED: Dict[Path, ModuleType] = {}
 
 
-@dataclasses.dataclass(frozen=True)
-class Spec:
-    name: str
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    norm: str            # rmsnorm | layernorm
-    norm_eps: float
-    mlp: str             # swiglu | gelu (tanh approximation)
-    qk_norm: bool
-    rope_theta: float
-    tied: bool
-    dtype: str
-    max_slots: int
-    block_size: int
-    max_seq_len: int
-    num_blocks: int
-
-    @property
-    def mlp_mats(self) -> int:
-        return 3 if self.mlp == "swiglu" else 2
-
-    def layer_matmul_params(self) -> int:
-        """Weights one token multiplies through in one layer."""
-        d, hd = self.d_model, self.head_dim
-        attn = d * (self.heads + 2 * self.kv_heads) * hd + self.heads * hd * d
-        return attn + self.mlp_mats * d * self.d_ff
-
-    def weight_bytes(self, itemsize: int = 2) -> int:
-        """Bytes of every weight matrix (norm vectors are negligible and
-        left out)."""
-        n = self.layers * self.layer_matmul_params() + self.vocab * self.d_model
-        if not self.tied:
-            n += self.vocab * self.d_model
-        return n * itemsize
-
-    def kv_bytes_per_token(self, itemsize: int = 2) -> int:
-        return 2 * self.layers * self.kv_heads * self.head_dim * itemsize
+def _found(archs: Path) -> list:
+    return sorted(p.stem for p in archs.glob("*.py"))
 
 
-def load_spec(path: Path) -> Spec:
+def load_arch(name: str, archs: Path = ARCHS) -> ModuleType:
+    """The module ``archs/<name>.py`` (loaded once per file), or
+    SystemExit naming the modules there."""
+    path = archs / f"{name}.py"
+    if not (_NAME.fullmatch(name) and path.is_file()):
+        raise SystemExit(f"no architecture module {name!r} ({path}); "
+                         f"modules in {archs}: {_found(archs)}")
+    path = path.resolve()
+    if path not in _LOADED:
+        # registered before it runs: a dataclass looks up its module
+        mod_name = f"onchip_arch_{len(_LOADED)}_{name}"
+        spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[mod_name] = mod
+        spec.loader.exec_module(mod)
+        _LOADED[path] = mod
+    return _LOADED[path]
+
+
+def load_config(path: Path, archs: Path = ARCHS
+                ) -> Tuple[dict, ModuleType]:
+    """The configuration file and the architecture module it names, or
+    SystemExit where it names none or one that is not in ``archs``."""
     with open(path) as f:
-        c = json.load(f)
-    prog, serve = c["program"], c["serving"]
-    heads = c["num_attention_heads"]
-    return Spec(
-        name=c["name"], layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], heads=heads,
-        kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim") or c["hidden_size"] // heads,
-        d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-        norm=prog["norm_type"],
-        norm_eps=float(c.get("rms_norm_eps", c.get("norm_epsilon", 1e-5))),
-        mlp=prog["mlp_type"], qk_norm=bool(prog["qk_norm"]),
-        rope_theta=float(c["rope_theta"]),
-        tied=bool(c["tie_word_embeddings"]), dtype=prog["dtype"],
-        max_slots=serve["max_slots"], block_size=serve["block_size"],
-        max_seq_len=serve["max_seq_len"], num_blocks=serve["num_blocks"])
+        config = json.load(f)
+    if not isinstance(config.get("arch"), str):
+        raise SystemExit(f"{path} names no architecture (its \"arch\" key); "
+                         f"modules in {archs}: {_found(archs)}")
+    return config, load_arch(config["arch"], archs)
 
 
-def program_config(spec: Spec):
-    """The system under test's ``ModelConfig`` for this spec."""
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=spec.name, family="dense", num_layers=spec.layers,
-        d_model=spec.d_model, num_heads=spec.heads,
-        num_kv_heads=spec.kv_heads, d_ff=spec.d_ff, vocab_size=spec.vocab,
-        head_dim=spec.head_dim, rope_theta=spec.rope_theta,
-        qk_norm=spec.qk_norm, mlp_type=spec.mlp, norm_type=spec.norm,
-        norm_eps=spec.norm_eps, tie_embeddings=spec.tied, dtype=spec.dtype,
-        max_seq_len=spec.max_seq_len)
+def load_spec(path: Path):
+    """The spec of a configuration file, made by its module."""
+    config, arch = load_config(path)
+    return arch.load_spec(config)
+
+
+def program_config(spec):
+    """The ``ModelConfig`` of a spec, from the module that made it."""
+    return sys.modules[type(spec).__module__].program_config(spec)

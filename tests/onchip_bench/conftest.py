@@ -12,7 +12,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT / "benchmarks"))
 
 TINY_CONFIG = {
-    "name": "tiny", "source": "a CPU-sized dense decoder",
+    "name": "tiny", "arch": "dense", "source": "a CPU-sized dense decoder",
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "vocab_size": 256, "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
@@ -49,11 +49,12 @@ class FakeChip:
 
 @pytest.fixture
 def bench_root(tmp_path):
-    """A copy of the benchmark's files (BENCHMARK.json, configs, mixes,
-    traces, readers, peaks) under ``tmp_path``, to which a test adds."""
+    """A copy of the benchmark's files (BENCHMARK.json, configs,
+    architecture modules, mixes, traces, readers) under ``tmp_path``, to
+    which a test adds."""
     src = ROOT / "benchmarks" / "onchip"
     dst = tmp_path / "benchmarks" / "onchip"
-    for sub in ("configs", "traffic", "traces", "metrics"):
+    for sub in ("configs", "archs", "traffic", "traces", "metrics"):
         shutil.copytree(src / sub, dst / sub)
     shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
     return tmp_path

@@ -45,7 +45,7 @@ def main() -> int:
     from record_trace import TRACE_CONFIG
     root = Path(tempfile.mkdtemp(prefix="record_spans_"))
     src = ROOT / "benchmarks" / "onchip"
-    for sub in ("configs", "traffic", "traces", "metrics"):
+    for sub in ("configs", "archs", "traffic", "traces", "metrics"):
         shutil.copytree(src / sub, root / "benchmarks" / "onchip" / sub)
     shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
     mix = dict(TINY_MIX, arrival={"process": "poisson", "rate": 8.0})

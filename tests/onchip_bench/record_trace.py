@@ -30,7 +30,8 @@ sys.path.insert(0, str(ROOT / "benchmarks"))
 sys.path.insert(0, str(ROOT / "src"))
 
 TRACE_CONFIG = {
-    "name": "tracetiny", "source": "a two-layer decoder at the chip's tile",
+    "name": "tracetiny", "arch": "dense",
+    "source": "a two-layer decoder at the chip's tile",
     "hidden_size": 256, "intermediate_size": 512, "num_hidden_layers": 2,
     "num_attention_heads": 2, "num_key_value_heads": 1, "head_dim": 128,
     "vocab_size": 512, "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
@@ -56,7 +57,7 @@ def main() -> int:
     from onchip import harness
     root = Path(tempfile.mkdtemp(prefix="record_trace_"))
     src = ROOT / "benchmarks" / "onchip"
-    for sub in ("configs", "traffic", "traces", "metrics"):
+    for sub in ("configs", "archs", "traffic", "traces", "metrics"):
         shutil.copytree(src / sub, root / "benchmarks" / "onchip" / sub)
     shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
     mix = dict(TINY_MIX, arrival={"process": "poisson", "rate": 8.0})

@@ -13,9 +13,10 @@ from pathlib import Path
 import pytest
 
 from onchip import breakdown, harness, trace_reduce, work
-from onchip.model import load_spec
+from onchip.model import load_arch
 
 DATA = Path(__file__).resolve().parent / "data"
+DENSE = load_arch("dense")        # the recorded config's architecture
 RUN = json.loads((DATA / "tiny_v5e_spans.json").read_text())
 
 
@@ -50,10 +51,9 @@ def test_program_spans_and_scopes_match_the_chip_run(pb):
     assert {"mlp", "kv_write"} <= {n for n, _ in got["device_scopes"]}
 
 
-def test_every_per_layer_number_matches_the_chip_run(tmp_path, pb):
-    cfg = tmp_path / "tracetiny.json"
-    cfg.write_text(json.dumps(RUN["config"]))
-    rec = harness.Record(spec=load_spec(cfg), peak=work.peaks("TPU v5 lite"),
+def test_every_per_layer_number_matches_the_chip_run(pb):
+    rec = harness.Record(arch=DENSE, spec=DENSE.load_spec(RUN["config"]),
+                         peak=work.peaks("TPU v5 lite"),
                          gauges=[types.SimpleNamespace(**g)
                                  for g in RUN["gauges"]],
                          decide_s=RUN["decide_s"],

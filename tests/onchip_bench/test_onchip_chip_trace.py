@@ -11,9 +11,10 @@ from pathlib import Path
 import pytest
 
 from onchip import harness, trace_reduce, work
-from onchip.model import load_spec
+from onchip.model import load_arch
 
 DATA = Path(__file__).resolve().parent / "data"
+DENSE = load_arch("dense")        # the recorded config's architecture
 RUN = json.loads((DATA / "tiny_v5e.json").read_text())
 
 
@@ -38,10 +39,8 @@ def test_planes_lines_and_names_are_those_the_reduction_reads(tmp_path):
 
 
 def test_per_layer_numbers_match_the_chip_run(tmp_path):
-    cfg = tmp_path / "tracetiny.json"
-    cfg.write_text(json.dumps(RUN["config"]))
-    spec = load_spec(cfg)
-    rec = harness.Record(spec=spec, peak=work.peaks("TPU v5 lite"),
+    rec = harness.Record(arch=DENSE, spec=DENSE.load_spec(RUN["config"]),
+                         peak=work.peaks("TPU v5 lite"),
                          gauges=[types.SimpleNamespace(**g)
                                  for g in RUN["gauges"]],
                          decide_s=RUN["decide_s"],

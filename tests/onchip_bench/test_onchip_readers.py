@@ -6,12 +6,13 @@ import pytest
 
 from conftest import ROOT
 from onchip import harness, work
-from onchip.model import load_spec
+from onchip.model import load_config
 from onchip.trace_reduce import Event, Trace
 
 METRICS = ROOT / "benchmarks" / "onchip" / "metrics"
-SPEC = load_spec(ROOT / "benchmarks" / "onchip" / "configs"
-                 / "qwen3-1.7b.json")
+CONFIG, ARCH = load_config(ROOT / "benchmarks" / "onchip" / "configs"
+                           / "qwen3-1.7b.json")
+SPEC = ARCH.load_spec(CONFIG)
 PEAK = work.peaks("TPU v5 lite")
 DEV = "/device:TPU:0"
 
@@ -31,7 +32,7 @@ class Gauge:
 
 
 def record(**kw):
-    rec = harness.Record(spec=SPEC, peak=PEAK)
+    rec = harness.Record(arch=ARCH, spec=SPEC, peak=PEAK)
     for k, v in kw.items():
         setattr(rec, k, v)
     return rec
@@ -68,15 +69,15 @@ def test_device_readers_against_work_functions():
                  Event("jit__prefill_paged(2)", 700_000_000,
                        700_000_000 + prefill_ns)],
         decode_ctx=ctx, prefill_n=[500])
-    least = sum(work.roofline_s(*work.decode_step(SPEC, c), PEAK)
+    least = sum(work.roofline_s(*ARCH.decode_step(SPEC, c), PEAK)
                 for c in ctx)
     assert reader("mfu.decode")(rec) == pytest.approx(
         100 * least / (decode_ns / 1e9))
     assert reader("mfu.prefill")(rec) == pytest.approx(
-        100 * work.roofline_s(*work.prefill(SPEC, 500), PEAK)
+        100 * work.roofline_s(*ARCH.prefill(SPEC, 500), PEAK)
         / (prefill_ns / 1e9))
     k_least = SPEC.layers * sum(
-        work.roofline_s(*work.paged_attention(SPEC, c), PEAK) for c in ctx)
+        work.roofline_s(*ARCH.paged_attention(SPEC, c), PEAK) for c in ctx)
     assert reader("decode_attention_paged_roofline")(rec) == pytest.approx(
         100 * k_least / (kernel_ns / 1e9))
     # busy: 2 ms of kernel and 100 ms of fusion in a 1 s window

@@ -7,8 +7,10 @@ import numpy as np
 import pytest
 
 from conftest import TINY_CONFIG, TINY_MIX, FakeChip, add_cell
-from onchip import harness, reference, weights
-from onchip.model import Spec, program_config
+from onchip import harness
+from onchip.model import load_arch
+
+dense = load_arch("dense")
 
 
 def _spec(**kw):
@@ -18,7 +20,7 @@ def _spec(**kw):
                 tied=True, dtype="float32", max_slots=2, block_size=16,
                 max_seq_len=128, num_blocks=32)
     base.update(kw)
-    return Spec(**base)
+    return dense.Spec(**base)
 
 
 @pytest.mark.parametrize("kw", [
@@ -31,22 +33,22 @@ def test_reference_matches_the_programs_forward(kw):
     both follow the same published equations."""
     from repro.models.model import forward_full
     spec = _spec(**kw)
-    params = weights.make_params(spec, 7)
+    params = dense.make_params(spec, 7)
     toks = np.random.default_rng(0).integers(0, spec.vocab, 37)
-    want = np.asarray(forward_full(params, program_config(spec),
+    want = np.asarray(forward_full(params, dense.program_config(spec),
                                    tokens=jnp.asarray(toks[None]))[0][0])
-    ref = reference.Reference(spec, params)
+    ref = dense.Reference(spec, params)
     x = ref._hidden("f32", toks)[:len(toks)]
-    got = np.asarray(reference._logits(spec, "f32", params["final_norm"],
-                                       reference._head_w(params, spec), x))
+    got = np.asarray(dense._logits(spec, "f32", params["final_norm"],
+                                   dense._head_w(params, spec), x))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
     # the reference's own greedy continuation reads gaps of zero
     seq, served = list(toks), []
     for _ in range(4):
         x = ref._hidden("f32", np.asarray(seq))[len(seq) - 1:len(seq)]
-        served.append(int(np.asarray(reference._logits(
+        served.append(int(np.asarray(dense._logits(
             spec, "f32", params["final_norm"],
-            reference._head_w(params, spec), x)).argmax()))
+            dense._head_w(params, spec), x)).argmax()))
         seq.append(served[-1])
     g, _ = ref.gaps(toks, served)
     assert len(g) == 4 and np.all(g <= 1e-4)

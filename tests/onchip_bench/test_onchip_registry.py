@@ -1,5 +1,6 @@
-"""A cell, a configuration, a mix and a per-layer metric added only as new
-files are found by name and run end to end, on the CPU at a tiny size.
+"""A cell, a configuration, an architecture module, a mix and a per-layer
+metric added only as new files are found by name and run end to end, on
+the CPU at a tiny size.
 
 The test stands in for the chip: it passes the harness a device object
 itself instead of the harness's own look for a TPU."""
@@ -47,6 +48,103 @@ def test_new_files_are_found_by_name_and_run(bench_root, trace):
         assert set(out["metrics"]) >= {"ttft_p95_ms", "tbt_p95_ms",
                                        "output_tok_s", "slo_attainment",
                                        "setup_s"}
+
+
+STUB_ARCH = '''"""A stand-in architecture: the dense module's functions,
+each call counted."""
+from collections import Counter
+from pathlib import Path
+
+from onchip import model
+
+_dense = model.load_arch("dense", Path(__file__).parent)
+CALLS = Counter()
+
+
+def _counted(name):
+    fn = getattr(_dense, name)
+
+    def call(*a, **k):
+        CALLS[name] += 1
+        return fn(*a, **k)
+    return call
+
+
+load_spec = _counted("load_spec")
+program_config = _counted("program_config")
+make_params = _counted("make_params")
+Reference = _counted("Reference")
+decode_step = _counted("decode_step")
+prefill = _counted("prefill")
+paged_attention = _counted("paged_attention")
+'''
+
+
+def _step_trace(rec):
+    """One run each of the decode and the prefill program in the window
+    (the CPU trace has no TPU plane), so the device readers count work."""
+    from onchip import trace_reduce as tr
+    dev = "/device:TPU:0"
+    rec.trace = tr.Trace(ops={dev: []}, modules={dev: [
+        tr.Event("jit__decode_dispatch_paged(1)", 0, 10**6),
+        tr.Event("jit__prefill_paged(2)", 2 * 10**6, 3 * 10**6)]},
+        spans=[])
+    rec.device, rec.window_ns = dev, (0, 10**9)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_new_architecture_runs_as_new_files(bench_root, monkeypatch,
+                                              trace):
+    """An ``archs/`` module, a config that names it and a workload entry
+    run end to end, and the harness and the readers reach the spec, the
+    weights, the reference and the work counts through that module."""
+    from onchip import harness
+    (bench_root / "benchmarks" / "onchip" / "archs" / "stub.py") \
+        .write_text(STUB_ARCH)
+    config = dict(TINY_CONFIG, name="stubtiny", arch="stub")
+    name = add_cell(bench_root, "tinymix", config, TINY_MIX)
+    kept = {}
+    per_layer = harness._per_layer
+
+    def keep(cell_, rec, *a, **k):
+        kept["rec"] = rec
+        return per_layer(cell_, rec, *a, **k)
+    monkeypatch.setattr(harness, "_per_layer", keep)
+    cell, out = _run(bench_root, name, bool(trace))
+    calls = cell.arch.CALLS
+    assert cell.arch.__file__.endswith("stub.py")
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert {k: calls[k] for k in ("load_spec", "program_config",
+                                  "make_params", "Reference")} \
+        == dict(load_spec=1, program_config=1, make_params=1, Reference=1)
+    if not trace:
+        assert calls["decode_step"] == calls["prefill"] == 0
+        return
+    rec = kept["rec"]
+    assert rec.arch is cell.arch and rec.decode_ctx and rec.prefill_n
+    _step_trace(rec)
+    readers = harness._load_readers(cell)
+    for m in ("mfu.decode", "mfu.prefill"):
+        assert readers[m].read(rec) > 0
+    assert calls["decode_step"] == sum(1 for c in rec.decode_ctx if c)
+    assert calls["prefill"] == sum(1 for n in rec.prefill_n if n > 0)
+
+
+def test_a_config_without_an_architecture_is_refused(bench_root):
+    from onchip import harness
+    config = {k: v for k, v in TINY_CONFIG.items() if k != "arch"}
+    name = add_cell(bench_root, "tinymix", config, TINY_MIX)
+    with pytest.raises(SystemExit, match="names no architecture.*dense"):
+        harness.load_cell(name, bench_root)
+
+
+def test_an_unknown_architecture_is_refused(bench_root):
+    from onchip import harness
+    config = dict(TINY_CONFIG, arch="no-such-arch")
+    name = add_cell(bench_root, "tinymix", config, TINY_MIX)
+    with pytest.raises(SystemExit, match="no-such-arch.*dense"):
+        harness.load_cell(name, bench_root)
 
 
 def test_unknown_workload_is_refused(bench_root):

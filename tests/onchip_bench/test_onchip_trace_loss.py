@@ -12,9 +12,10 @@ from pathlib import Path
 import pytest
 
 from onchip import harness, trace_reduce, work
-from onchip.model import load_spec
+from onchip.model import load_arch
 
 DATA = Path(__file__).resolve().parent / "data"
+DENSE = load_arch("dense")        # the recorded config's architecture
 RUN = json.loads((DATA / "tiny_v5e_spans.json").read_text())
 DEVICE_SHARES = ("mfu.decode", "mfu.prefill",
                  "decode_attention_paged_roofline", "device.idle_share")
@@ -43,9 +44,8 @@ def _read(tmp_path, monkeypatch, share=None, dropped=0):
         tr.dropped = {d: dropped for d in tr.ops}
         return tr if share is None else _cut(tr, share)
     monkeypatch.setattr(trace_reduce, "load", cut)
-    cfg = tmp_path / "tracetiny.json"
-    cfg.write_text(json.dumps(RUN["config"]))
-    rec = harness.Record(spec=load_spec(cfg), peak=work.peaks("TPU v5 lite"),
+    rec = harness.Record(arch=DENSE, spec=DENSE.load_spec(RUN["config"]),
+                         peak=work.peaks("TPU v5 lite"),
                          gauges=[types.SimpleNamespace(**g)
                                  for g in RUN["gauges"]],
                          decide_s=list(RUN["decide_s"]),

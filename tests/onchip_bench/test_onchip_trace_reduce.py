@@ -60,7 +60,8 @@ def test_the_traced_part_ends_at_the_last_device_event():
 
 def _part(modules, spans=SPANS, steps=10, dropped=0):
     from onchip import harness
-    rec = harness.Record(spec=None, peak={}, device="/device:TPU:0",
+    rec = harness.Record(arch=None, spec=None, peak={},
+                         device="/device:TPU:0",
                          decode_ctx=[[i] for i in range(steps)])
     rec.trace = tr.Trace(ops={rec.device: modules},
                          modules={rec.device: modules}, spans=spans,

@@ -1,5 +1,5 @@
-"""Work functions, the peaks table, the configuration files and the
-weights tree (CPU; counts and shapes only)."""
+"""The dense architecture module's work functions and weights tree, the
+peaks table and the configuration files (CPU; counts and shapes only)."""
 import dataclasses
 import functools
 
@@ -8,9 +8,10 @@ import pytest
 
 from conftest import ROOT
 from onchip import weights, work
-from onchip.model import load_spec, program_config
+from onchip.model import load_arch, load_spec
 
 CONFIGS = ROOT / "benchmarks" / "onchip" / "configs"
+dense = load_arch("dense")
 
 # a small spec whose counts are easy by hand: d=8, 2 layers, 2 q heads
 # and 1 kv head of 4, MLP 16 (SwiGLU), vocab 10, tied
@@ -29,7 +30,7 @@ def test_layer_and_head_counts_by_hand():
 
 
 def test_decode_step_by_hand():
-    flops, nbytes = work.decode_step(SMALL, [3, 5])
+    flops, nbytes = dense.decode_step(SMALL, [3, 5])
     # per token 2*2*576 + 2*8*10 = 2464; attention 4*2 layers*2 heads*4*8
     assert flops == 2 * 2464 + 4 * 2 * 2 * 4 * 8
     # weights 2*2*576 + 2*10*8 = 2464; K/V read 8 tokens, written 2
@@ -37,14 +38,14 @@ def test_decode_step_by_hand():
 
 
 def test_prefill_by_hand():
-    flops, nbytes = work.prefill(SMALL, 3)
+    flops, nbytes = dense.prefill(SMALL, 3)
     # 3 tokens x 2*2*576, causal attention 4*2*2*4*(3*4/2), head 2*8*10
     assert flops == 3 * 2304 + 4 * 2 * 2 * 4 * 6 + 160
     assert nbytes == 2464 + 32 * 3
 
 
 def test_paged_attention_by_hand():
-    flops, nbytes = work.paged_attention(SMALL, [3, 5])
+    flops, nbytes = dense.paged_attention(SMALL, [3, 5])
     assert flops == 4 * 2 * 4 * 8
     # K and V of one layer: 2 * 1 head * 4 * 2 bytes per token; q and o
     assert nbytes == 16 * 8 + 2 * 2 * 2 * 4 * 2
@@ -79,10 +80,10 @@ def test_weights_tree_is_the_programs_layout(spec):
     """The benchmark's tree has the leaves, shapes and dtypes of the
     program's own initialiser (shapes only, nothing is computed)."""
     from repro.models import init_params
-    ours = jax.eval_shape(functools.partial(weights._tree, spec),
+    ours = jax.eval_shape(functools.partial(dense._tree, spec),
                           weights.seed_key(0))
     theirs = jax.eval_shape(functools.partial(
-        init_params, cfg=program_config(spec)), jax.random.PRNGKey(0))
+        init_params, cfg=dense.program_config(spec)), jax.random.PRNGKey(0))
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
         assert (a.shape, a.dtype) == (b.shape, b.dtype)
